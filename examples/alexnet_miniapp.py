@@ -48,6 +48,7 @@ from repro.configs import ALEXNET_SMOKE as CFG
 from repro.core import CheckpointManager, IOTracer, ResumableIterator, \
     image_pipeline, make_storage, sharded_image_pipeline
 from repro.core import records
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import alexnet as A
 from repro.train.trainer import Trainer
 
@@ -94,6 +95,7 @@ def main():
                     help="graceful-shutdown budget in seconds (with "
                          "--preempt-at; default 5)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.resume and not args.ckpt:
         ap.error("--resume requires --ckpt DIR")
     if args.preempt_at is not None and not args.ckpt:
@@ -144,14 +146,7 @@ def main():
 
     params = A.init_params(jax.random.PRNGKey(0), CFG)
     state = {"params": params, "step": jnp.int32(0)}
-
-    @jax.jit
-    def train_step(state, batch):
-        imgs, lbls = batch
-        loss, g = jax.value_and_grad(
-            lambda p: A.loss_fn(p, imgs, lbls, CFG))(state["params"])
-        new_p = jax.tree.map(lambda p, gg: p - 1e-4 * gg, state["params"], g)
-        return {"params": new_p, "step": state["step"] + 1}, {"loss": loss}
+    train_step = A.make_train_step(CFG)
 
     collector = trace.start() if args.trace else None
     sampler = None

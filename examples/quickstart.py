@@ -16,12 +16,14 @@ import numpy as np
 from repro.configs import ARCHS
 from repro.core import BurstBufferCheckpointer, Dataset, make_storage
 from repro.core import records
+from repro.launch.compile_cache import use_compile_cache
 from repro.train import steps as S
 from repro.train.optimizer import OptConfig
 from repro.train.trainer import Trainer
 
 
 def main():
+    use_compile_cache()
     cfg = ARCHS["qwen3-4b"].smoke()
     opt = OptConfig(lr=3e-3)
     root = tempfile.mkdtemp()

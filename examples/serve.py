@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCHS
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.registry import model_fns
 
 
@@ -23,6 +24,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = ARCHS[args.arch].smoke()
     fns = model_fns(cfg)

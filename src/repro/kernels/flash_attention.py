@@ -16,10 +16,13 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from . import resolve_interpret
 
 DEFAULT_BQ = 512
 DEFAULT_BK = 512
@@ -72,7 +75,7 @@ def flash_attention(
     causal: bool = True,
     bq: int = DEFAULT_BQ,
     bk: int = DEFAULT_BK,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     BH, Sq, hd = q.shape
     Skv = k.shape[1]
@@ -92,5 +95,5 @@ def flash_attention(
         ],
         out_specs=pl.BlockSpec((None, bq, hd), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, hd), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

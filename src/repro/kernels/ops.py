@@ -1,12 +1,13 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True (this container is CPU-only; TPU is the
-lowering target).  On a real TPU deployment pass ``interpret=False``.
+``interpret=None`` (the default) compiles every kernel on an accelerator
+and interprets it on the CPU backend (see :func:`repro.kernels.
+resolve_interpret`); pass a bool only to override that.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +21,7 @@ BLOCK = _q.BLOCK
 
 # -- quantize ----------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def quantize(x: jax.Array, *, interpret: bool = True):
+def quantize(x: jax.Array, *, interpret: Optional[bool] = None):
     """Any-shape tensor -> (q (n,BLOCK) int8, scales (n,1) f32, meta).
 
     meta = (shape, pad) needed by :func:`dequantize`."""
@@ -34,7 +35,7 @@ def quantize(x: jax.Array, *, interpret: bool = True):
 
 
 def dequantize(q: jax.Array, s: jax.Array, shape, dtype=jnp.float32,
-               *, interpret: bool = True) -> jax.Array:
+               *, interpret: Optional[bool] = None) -> jax.Array:
     flat = _q.dequantize_blocks(q, s, interpret=interpret).reshape(-1)
     n = 1
     for d in shape:
@@ -45,7 +46,7 @@ def dequantize(q: jax.Array, s: jax.Array, shape, dtype=jnp.float32,
 # -- preprocess -----------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def normalize_images_nhwc(x: jax.Array, mean: jax.Array, std: jax.Array,
-                          *, interpret: bool = True) -> jax.Array:
+                          *, interpret: Optional[bool] = None) -> jax.Array:
     """x: (B, H, W, C) uint8 -> normalized (B, H, W, C) f32 (fused kernel)."""
     B, H, W, C = x.shape
     xc = jnp.transpose(x, (0, 3, 1, 2)).reshape(B, C, H * W)
@@ -53,20 +54,17 @@ def normalize_images_nhwc(x: jax.Array, mean: jax.Array, std: jax.Array,
     return jnp.transpose(out.reshape(B, C, H, W), (0, 2, 3, 1))
 
 
-@functools.partial(jax.jit, static_argnames=("out_h", "out_w", "interpret"))
-def resize_convert_nhwc(x: jax.Array, out_h: int, out_w: int,
-                        *, interpret: bool = True) -> jax.Array:
-    """x: (B, H, W, C) u8/u16/f32 -> (B, out_h, out_w, C) f32 in [0,1]
-    (fused matmul-bilinear resize + dtype-convert kernel)."""
-    return _pre.resize_convert_images(x, out_h, out_w, interpret=interpret)
+# (B, H, W, C) u8/u16/f32 -> (B, out_h, out_w, C) f32 in [0,1]; the kernel's
+# own entry point is already jitted (the input pipeline calls it directly)
+resize_convert_nhwc = _pre.resize_convert_images
 
 
 # -- flash attention ---------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array,
                          *, causal: bool = True, bq: int = _fa.DEFAULT_BQ,
-                         bk: int = _fa.DEFAULT_BK, interpret: bool = True
-                         ) -> jax.Array:
+                         bk: int = _fa.DEFAULT_BK,
+                         interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, Sq, H, hd), k/v: (B, Skv, Hkv, hd) GQA -> (B, Sq, H, hd)."""
     B, Sq, H, hd = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]

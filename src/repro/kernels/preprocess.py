@@ -14,32 +14,45 @@ host decodes, device does the arithmetic.  Two kernels:
   between this kernel and the batched numpy LUT-gather fallback
   (:func:`repro.core.records.resize_batch`) on CPU-only hosts.
 
-TPU layout choice for normalize: NHWC with C=3 would waste 128-wide lanes,
-so the wrapper moves channels to the sublane dim: (B, C, H*W).  Each grid
-step handles one image's (C, PIX_TILE) tile; mean/std live in SMEM-like
-small refs (C, 1).
+TPU layout: NHWC with C=3 would put 3 values on the 128-wide lane axis,
+which Mosaic cannot reshape for a matmul.  Normalize moves channels to the
+sublane dim, (B, C, H*W): each grid step handles one image's (C, PIX_TILE)
+tile, and mean/std live in small (C, 1) refs.  Resize works on per-channel
+planes, (B*C, H, W): each grid step is two plain 2-D matmuls with W on the
+lanes.  Mosaic has no direct uint8 -> float32 cast, so integer pixels widen
+through int32 first.
 """
 from __future__ import annotations
 
+import functools
 from functools import lru_cache
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from . import resolve_interpret
+
 PIX_TILE = 2048
 
 
+def _to_f32(x: jax.Array) -> jax.Array:
+    if jnp.issubdtype(x.dtype, jnp.integer):
+        x = x.astype(jnp.int32)
+    return x.astype(jnp.float32)
+
+
 def _normalize_kernel(x_ref, mean_ref, std_ref, o_ref):
-    x = x_ref[...].astype(jnp.float32) * (1.0 / 255.0)   # (1, C, T)
+    x = _to_f32(x_ref[...]) * (1.0 / 255.0)              # (1, C, T)
     mean = mean_ref[...][None, :, :]                     # (1, C, 1)
     std = std_ref[...][None, :, :]
     o_ref[...] = (x - mean) / std
 
 
 def normalize_images(x: jax.Array, mean: jax.Array, std: jax.Array,
-                     *, interpret: bool = True) -> jax.Array:
+                     *, interpret: Optional[bool] = None) -> jax.Array:
     """x: (B, C, P) uint8, mean/std: (C,) -> (B, C, P) float32."""
     B, C, P = x.shape
     tile = min(PIX_TILE, P)
@@ -54,7 +67,7 @@ def normalize_images(x: jax.Array, mean: jax.Array, std: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, C, tile), lambda b, i: (b, 0, i)),
         out_shape=jax.ShapeDtypeStruct((B, C, P), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, mean.reshape(C, 1), std.reshape(C, 1))
 
 
@@ -62,6 +75,10 @@ def normalize_images(x: jax.Array, mean: jax.Array, std: jax.Array,
 # Batched bilinear resize + dtype convert
 # ---------------------------------------------------------------------------
 from ..core.records import CONVERT_SCALE as _CONVERT_SCALE  # noqa: E402
+
+# f32 matmuls at full precision: the default single bf16 pass would round
+# the interpolation weights far outside the numpy path's tolerance
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 @lru_cache(maxsize=64)
@@ -80,45 +97,43 @@ def _interp_matrix(n_in: int, n_out: int, scale: float = 1.0) -> np.ndarray:
     return m
 
 
-def _make_resize_convert_kernel(scale: float):
-    def kernel(x_ref, ry_ref, rx_ref, o_ref):
-        x = x_ref[0].astype(jnp.float32)          # (H, W, C)
-        ry = ry_ref[...]                          # (OH, H), scale folded in
-        rx = rx_ref[...]                          # (OW, W)
-        t = jnp.einsum("oh,hwc->owc", ry, x,
-                       preferred_element_type=jnp.float32)
-        o_ref[0] = jnp.einsum("pw,owc->opc", rx, t,
-                              preferred_element_type=jnp.float32)
-    kernel.__name__ = f"resize_convert_kernel_s{scale:g}"
-    return kernel
+def _resize_convert_kernel(x_ref, ry_ref, rxt_ref, o_ref):
+    x = _to_f32(x_ref[...])                                  # (H, W) one plane
+    t = jnp.dot(ry_ref[...], x, precision=_HIGHEST,          # (OH, W)
+                preferred_element_type=jnp.float32)
+    o_ref[...] = jnp.dot(t, rxt_ref[...], precision=_HIGHEST,  # (OH, OW)
+                         preferred_element_type=jnp.float32)
 
 
+@functools.partial(jax.jit, static_argnames=("out_h", "out_w", "interpret"))
 def resize_convert_images(x: jax.Array, out_h: int, out_w: int,
-                          *, interpret: bool = True) -> jax.Array:
+                          *, interpret: Optional[bool] = None) -> jax.Array:
     """Batched device-side resize+convert: (B,H,W,C) u8/u16/f32 ->
     (B,out_h,out_w,C) f32 in [0,1].
 
-    One grid step per image; both interpolation matmuls run on the MXU with
-    the dtype-conversion scale folded into the row matrix.  Requires a
-    uniform-size batch (H, W shared) — the sharded-corpus writers emit one
-    with ``hw_jitter=0``.
+    One grid step per channel plane; both interpolation matmuls run on the
+    MXU with the dtype-conversion scale folded into the row matrix.
+    Requires a uniform-size batch (H, W shared) — the sharded-corpus
+    writers emit one with ``hw_jitter=0``.
     """
     B, H, W, C = x.shape
     scale = float(_CONVERT_SCALE.get(np.dtype(x.dtype), 1.0))
-    ry = jnp.asarray(_interp_matrix(H, out_h, scale))
-    rx = jnp.asarray(_interp_matrix(W, out_w))
-    return pl.pallas_call(
-        _make_resize_convert_kernel(scale),
-        grid=(B,),
+    ry = jnp.asarray(_interp_matrix(H, out_h, scale))        # (OH, H)
+    rxt = jnp.asarray(_interp_matrix(W, out_w).T)            # (W, OW)
+    planes = jnp.transpose(x, (0, 3, 1, 2)).reshape(B * C, H, W)
+    out = pl.pallas_call(
+        _resize_convert_kernel,
+        grid=(B * C,),
         in_specs=[
-            pl.BlockSpec((1, H, W, C), lambda b: (b, 0, 0, 0)),
-            pl.BlockSpec((out_h, H), lambda b: (0, 0)),
-            pl.BlockSpec((out_w, W), lambda b: (0, 0)),
+            pl.BlockSpec((None, H, W), lambda p: (p, 0, 0)),
+            pl.BlockSpec((out_h, H), lambda p: (0, 0)),
+            pl.BlockSpec((W, out_w), lambda p: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, out_h, out_w, C), lambda b: (b, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, out_h, out_w, C), jnp.float32),
-        interpret=interpret,
-    )(x, ry, rx)
+        out_specs=pl.BlockSpec((None, out_h, out_w), lambda p: (p, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B * C, out_h, out_w), jnp.float32),
+        interpret=resolve_interpret(interpret),
+    )(planes, ry, rxt)
+    return jnp.transpose(out.reshape(B, C, out_h, out_w), (0, 2, 3, 1))
 
 
 def resize_convert_batch_np(x: np.ndarray, out_h: int, out_w: int) -> np.ndarray:
@@ -133,16 +148,15 @@ def resize_convert_batch_np(x: np.ndarray, out_h: int, out_w: int) -> np.ndarray
     return records.resize_batch(x, out_h, out_w, scale=scale)
 
 
-def resize_convert(x, out_h: int, out_w: int, *, backend: str = "auto",
-                   interpret: bool = True):
-    """Dispatch batched resize+convert: ``"pallas"`` (device kernel),
-    ``"numpy"`` (host LUT gather), or ``"auto"`` (kernel only when a real
-    accelerator backend is present)."""
+def resize_convert(x, out_h: int, out_w: int, *, backend: str = "auto"):
+    """Dispatch batched resize+convert: ``"pallas"`` (device kernel, compiled
+    on an accelerator, interpreted on the CPU backend), ``"numpy"`` (host
+    LUT gather), or ``"auto"`` (kernel only when a real accelerator backend
+    is present)."""
     if backend == "auto":
         backend = "numpy" if jax.default_backend() == "cpu" else "pallas"
     if backend == "numpy":
         return resize_convert_batch_np(np.asarray(x), out_h, out_w)
     if backend == "pallas":
-        return resize_convert_images(jnp.asarray(x), out_h, out_w,
-                                     interpret=interpret)
+        return resize_convert_images(jnp.asarray(x), out_h, out_w)
     raise ValueError(f"unknown backend {backend!r}; options: auto/numpy/pallas")

@@ -15,10 +15,13 @@ sublanes x 256 lanes of fp32 in, int8 out + (ROWS_PER_TILE, 1) scales.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from . import resolve_interpret
 
 BLOCK = 256
 ROWS_PER_TILE = 256
@@ -37,7 +40,7 @@ def _dequant_kernel(q_ref, s_ref, x_ref):
     x_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[...]
 
 
-def quantize_blocks(x: jax.Array, *, interpret: bool = True):
+def quantize_blocks(x: jax.Array, *, interpret: Optional[bool] = None):
     """x: (n_blocks, BLOCK) fp32/bf16 -> (q int8, scales fp32 (n_blocks,1))."""
     n, b = x.shape
     assert b == BLOCK, f"expected block dim {BLOCK}, got {b}"
@@ -55,11 +58,12 @@ def quantize_blocks(x: jax.Array, *, interpret: bool = True):
             jax.ShapeDtypeStruct((n, BLOCK), jnp.int8),
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x)
 
 
-def dequantize_blocks(q: jax.Array, s: jax.Array, *, interpret: bool = True):
+def dequantize_blocks(q: jax.Array, s: jax.Array, *,
+                      interpret: Optional[bool] = None):
     """(q int8 (n,BLOCK), scales (n,1)) -> fp32 (n, BLOCK)."""
     n, b = q.shape
     assert b == BLOCK
@@ -74,5 +78,5 @@ def dequantize_blocks(q: jax.Array, s: jax.Array, *, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((rows, BLOCK), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, BLOCK), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, s)

@@ -32,6 +32,7 @@ from ..roofline.analysis import analyze_compiled, model_flops_for
 from ..sharding.rules import ShardingCtx
 from ..train import steps as steps_lib
 from ..train.optimizer import OptConfig
+from .compile_cache import use_compile_cache
 from .mesh import devices_per_pod, make_production_mesh
 
 
@@ -203,14 +204,7 @@ def main() -> None:
     ap.add_argument("--kv-chunk", type=int, default=512)
     args = ap.parse_args()
 
-    try:
-        import os as _os
-        cache_dir = "/tmp/jax_cache"
-        _os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 5)
-    except Exception:
-        pass
+    use_compile_cache()
 
     archs = [args.arch] if args.arch else list(ARCHS)
     meshes = []

@@ -1,8 +1,8 @@
 """AlexNet — the paper's mini-application network (§III-B, ~200 lines in TF).
 
-5 conv (ReLU) + 3 maxpool + 3 FC, softmax-xent loss, Adam — exactly the
-paper's workload shape: per-batch compute long enough that the prefetcher
-can hide the input pipeline behind it.
+5 conv (ReLU) + 3 maxpool + 3 FC, softmax-xent loss, plain SGD — exactly
+the paper's workload shape: per-batch compute long enough that the
+prefetcher can hide the input pipeline behind it.
 """
 from __future__ import annotations
 
@@ -76,3 +76,19 @@ def loss_fn(params, images: Array, labels: Array, cfg) -> Array:
     logp = jax.nn.log_softmax(logits)
     onehot = jax.nn.one_hot(labels, cfg.n_classes)
     return -jnp.mean(jnp.sum(onehot * logp, axis=-1))
+
+
+def make_train_step(cfg):
+    """The mini-app's jitted SGD step at ``cfg.lr``:
+    ``({"params", "step"}, (images, labels)) -> (state, {"loss"})``."""
+
+    @jax.jit
+    def train_step(state, batch):
+        images, labels = batch
+        loss, grads = jax.value_and_grad(
+            lambda p: loss_fn(p, images, labels, cfg))(state["params"])
+        params = jax.tree.map(lambda p, g: p - cfg.lr * g,
+                              state["params"], grads)
+        return {"params": params, "step": state["step"] + 1}, {"loss": loss}
+
+    return train_step

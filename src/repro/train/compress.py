@@ -64,12 +64,6 @@ def compressed_allreduce_stacked(mesh, x: jax.Array, axis_name: str = "pod"
 
     nd = x.ndim
     spec = P(axis_name, *([None] * (nd - 1)))
-    if hasattr(jax, "shard_map"):
-        f = jax.shard_map(per_shard, mesh=mesh, in_specs=spec,
-                          out_specs=spec, check_vma=False)
-    else:  # older jax: experimental location, check_rep instead of check_vma
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        f = _shard_map(per_shard, mesh=mesh, in_specs=spec,
-                       out_specs=spec, check_rep=False)
+    f = jax.shard_map(per_shard, mesh=mesh, in_specs=spec, out_specs=spec,
+                      check_vma=False)
     return f(x)[0]

@@ -152,10 +152,8 @@ class TestElastic:
         t = {"w": np.arange(64, dtype=np.float32).reshape(8, 8)}
         saver = CheckpointSaver(tmp_storage, "ckpt/m")
         saver.save(3, t)
-        mesh_kw = {}
-        if hasattr(jax.sharding, "AxisType"):  # absent on older jax
-            mesh_kw["axis_types"] = (jax.sharding.AxisType.Auto,)
-        mesh = jax.make_mesh((1,), ("data",), **mesh_kw)
+        mesh = jax.make_mesh((1,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         sh = {"w": NamedSharding(mesh, P("data", None))}
         out = saver.restore_sharded(t, sh)
         np.testing.assert_array_equal(np.asarray(out["w"]), t["w"])

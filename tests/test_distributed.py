@@ -9,19 +9,19 @@ import textwrap
 import pytest
 
 _ENV = dict(os.environ,
+            JAX_PLATFORMS="cpu",
             XLA_FLAGS="--xla_force_host_platform_device_count=8",
             PYTHONPATH="src")
 
-# prepended to every subprocess: mesh construction that works with and
-# without jax.sharding.AxisType (absent on older jax)
+# prepended to every subprocess: meshes with Auto axes, so shardings
+# propagate through jit as in the library's own steps
 _PREAMBLE = textwrap.dedent("""
     import jax as _jax_compat
 
     def make_mesh(shape, names):
-        kw = {}
-        if hasattr(_jax_compat.sharding, "AxisType"):
-            kw["axis_types"] = (_jax_compat.sharding.AxisType.Auto,) * len(shape)
-        return _jax_compat.make_mesh(shape, names, **kw)
+        return _jax_compat.make_mesh(
+            shape, names,
+            axis_types=(_jax_compat.sharding.AxisType.Auto,) * len(shape))
 """)
 
 

@@ -172,3 +172,13 @@ class TestFlashAttentionKernel:
         o_model = chunked_attention(q, k, v, causal=True, q_chunk=64, kv_chunk=64)
         np.testing.assert_allclose(np.asarray(o_kernel), np.asarray(o_model),
                                    atol=3e-5, rtol=1e-3)
+
+
+class TestInterpretDefault:
+    def test_cpu_backend_interprets_and_a_bool_wins(self):
+        from repro.kernels import resolve_interpret
+
+        assert jax.default_backend() == "cpu"
+        assert resolve_interpret(None) is True
+        assert resolve_interpret(False) is False
+        assert resolve_interpret(True) is True
