@@ -78,7 +78,9 @@ class AsyncBurstBufferCheckpointer(BurstBufferCheckpointer):
                 "save() on a preempted AsyncBurstBufferCheckpointer")
         m = metrics.enabled()
         t0 = time.monotonic()
-        self._sema.acquire()  # backpressure: at most max_pending snapshots
+        # backpressure: at most max_pending snapshots
+        with trace.span(trace.STAGE_CKPT_BACKPRESSURE, "ckpt_backpressure"):
+            self._sema.acquire()
         try:
             t_snap = time.monotonic()
             with trace.span(trace.STAGE_CKPT_SNAPSHOT,

@@ -965,27 +965,41 @@ class ResumableIterator:
     def __next__(self) -> Any:
         if self._done:
             raise StopIteration
-        if self._it is None:
-            self._it = self._open_epoch(self._epoch)
-        while True:
+        if self._it is not None:
             try:
                 item = next(self._it)
             except StopIteration:
-                _close_iter(self._it)
-                self._it = None
-                empty_epoch = self._offset == 0
-                self._epoch += 1
-                self._offset = 0
-                if (self.epochs is not None and self._epoch >= self.epochs) \
-                        or empty_epoch:
-                    # empty epoch: the source is exhausted/empty — stop
-                    # instead of spinning on zero-element epochs forever
-                    self._done = True
-                    raise
-                self._it = self._open_epoch(self._epoch)
-                continue
-            self._offset += 1
-            return item
+                self._end_epoch()
+            else:
+                self._offset += 1
+                return item
+        # closing the finished epoch, opening the next, its first element
+        with trace.span(trace.STAGE_EPOCH_OPEN, "epoch_open"):
+            while True:
+                if self._it is None:
+                    self._it = self._open_epoch(self._epoch)
+                try:
+                    item = next(self._it)
+                except StopIteration:
+                    self._end_epoch()
+                    continue
+                self._offset += 1
+                return item
+
+    def _end_epoch(self) -> None:
+        """Close the exhausted epoch and move to the next; raises
+        ``StopIteration`` when there is none."""
+        _close_iter(self._it)
+        self._it = None
+        empty_epoch = self._offset == 0
+        self._epoch += 1
+        self._offset = 0
+        if (self.epochs is not None and self._epoch >= self.epochs) \
+                or empty_epoch:
+            # empty epoch: the source is exhausted/empty — stop instead of
+            # spinning on zero-element epochs forever
+            self._done = True
+            raise StopIteration
 
     def close(self) -> None:
         if self._it is not None:
@@ -1231,12 +1245,14 @@ def sharded_image_pipeline(
         ds = ds.batch(batch_size, drop_remainder=True)
 
         def batch_resize(batch):
-            if labels_per_shard is not None:
-                imgs, labels = batch
-                return kpre.resize_convert(
-                    imgs, *out_hw, backend=batched_preprocess), labels
-            return kpre.resize_convert(batch, *out_hw,
-                                       backend=batched_preprocess)
+            imgs = batch[0] if labels_per_shard is not None else batch
+            # with the kernel: the uint8 batch's copy to the device and the
+            # kernel's dispatch, on the pipeline's thread
+            with trace.span(trace.STAGE_DEVICE_PREPROCESS,
+                            batched_preprocess, imgs.nbytes):
+                out = kpre.resize_convert(imgs, *out_hw,
+                                          backend=batched_preprocess)
+            return (out, batch[1]) if labels_per_shard is not None else out
 
         ds = ds.map(batch_resize)
     else:

@@ -54,7 +54,7 @@ from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Set, Tuple
 
-from .. import metrics
+from .. import metrics, trace
 from .async_burst_buffer import AsyncBurstBufferCheckpointer
 from .async_checkpoint import AsyncCheckpointer
 from .burst_buffer import BurstBufferCheckpointer, DirectCheckpointer
@@ -459,7 +459,9 @@ class CheckpointManager:
                 except (OSError, ValueError, KeyError):
                     if storage is self.storage:
                         raise  # slow tier was the last resort: error parity
-        for s in reversed(self.valid_steps()):
+        with trace.span(trace.STAGE_CKPT_VALIDATE, "ckpt_validate"):
+            candidates = self.valid_steps()
+        for s in reversed(candidates):
             for storage, saver in self._tiers():
                 if not validate_step(storage, self.prefix, s):
                     continue
@@ -488,7 +490,9 @@ class CheckpointManager:
         ``data_iter`` supports ``restore_state``, the iterator is
         re-positioned so the resumed run neither skips nor replays samples.
         With no checkpoint at all, returns a fresh :class:`ResumeResult`
-        (``step=None``, skeleton untouched).
+        (``step=None``, skeleton untouched).  Traced as ``STAGE_CKPT_VALIDATE``
+        (finding the newest valid step), ``STAGE_CKPT_RESTORE`` and
+        ``STAGE_ITERATOR_SEEK``.
         """
         import jax
 
@@ -504,7 +508,8 @@ class CheckpointManager:
         pipeline = (meta.get("extra") or {}).get("pipeline")
         if data_iter is not None and pipeline is not None \
                 and hasattr(data_iter, "restore_state"):
-            data_iter.restore_state(pipeline)
+            with trace.span(trace.STAGE_ITERATOR_SEEK, "iterator_seek"):
+                data_iter.restore_state(pipeline)
         return ResumeResult(step=s, state=state, meta=meta,
                             pipeline=pipeline,
                             restore_s=time.monotonic() - t0)
@@ -540,4 +545,5 @@ class CheckpointManager:
         if self._closed:
             return
         self._closed = True
-        self.engine.close()
+        with trace.span(trace.STAGE_CKPT_CLOSE, "ckpt_close"):
+            self.engine.close()

@@ -1,28 +1,17 @@
 """dstat-like I/O activity tracing (paper §IV-B, Fig. 8/10).
 
 The paper traces disk activity with ``dstat`` at 1 Hz and plots MB read/written
-per second.  :class:`IOTracer` reproduces that view as an adapter over the
-fine-grained :mod:`repro.trace` machinery: the per-interval buckets are
-folded incrementally (bounded memory, like dstat itself), and setting
-``keep_events`` additionally lands every ``record()`` as an instant event in
-a private :class:`repro.trace.Tracer` — exposing the raw per-op log to the
-span/export tooling.  Callers that want per-operation spans everywhere
-should use :mod:`repro.trace` directly.
+per second.  :class:`IOTracer` reproduces that view: the per-interval
+buckets are folded incrementally (bounded memory, like dstat itself).
+Per-operation spans come from :mod:`repro.trace`, which the storage layer
+reports to as well.
 """
 from __future__ import annotations
 
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Tuple
-
-from .. import trace as _trace
-
-_KIND_STAGE = {
-    "read": _trace.STAGE_STORAGE_READ,
-    "write": _trace.STAGE_STORAGE_WRITE,
-}
-_STAGE_KIND = {v: k for k, v in _KIND_STAGE.items()}
+from typing import Dict, List
 
 
 @dataclass
@@ -37,34 +26,22 @@ class IOTracer:
     """Thread-safe per-interval I/O byte counter (dstat analogue).
 
     Buckets are folded incrementally in ``record()`` so memory stays
-    O(run length / interval), independent of op count.  With
-    ``keep_events`` set, each op is also recorded as an instant event in
-    the private :class:`repro.trace.Tracer` exposed as :attr:`collector`
-    (per-op log for export/report tooling — unbounded, hence opt-in).
+    O(run length / interval), independent of op count.
     """
 
     def __init__(self, interval_s: float = 1.0):
         self.interval_s = float(interval_s)
-        self.keep_events = False
         self._lock = threading.Lock()
         self._buckets: Dict[int, _Bucket] = {}
-        self._collector = _trace.Tracer(enabled=True)
         self._t0 = time.monotonic()
-
-    @property
-    def collector(self) -> "_trace.Tracer":
-        """Raw per-op span collector (populated when ``keep_events``)."""
-        return self._collector
 
     def reset(self) -> None:
         with self._lock:
             self._buckets.clear()
             self._t0 = time.monotonic()
-        self._collector.reset()
 
-    def record(self, kind: str, nbytes: int, tag: str = "") -> None:
-        stage = _KIND_STAGE.get(kind)
-        if stage is None:
+    def record(self, kind: str, nbytes: int) -> None:
+        if kind not in ("read", "write"):
             raise ValueError(
                 f"unknown I/O kind {kind!r}; expected 'read' or 'write'"
             )
@@ -78,17 +55,6 @@ class IOTracer:
             else:
                 b.write_bytes += nbytes
                 b.write_ops += 1
-        if self.keep_events:
-            self._collector.instant(stage, tag, nbytes, t=t)
-
-    # -- raw log (API compat: populated only when keep_events is set) -------
-    @property
-    def events(self) -> List[tuple]:
-        """(t, kind, nbytes, tag) rows, empty unless ``keep_events``."""
-        return [
-            (r.t0, _STAGE_KIND.get(r.stage, r.stage), r.nbytes, r.name)
-            for r in self._collector.spans()
-        ]
 
     # -- reporting ---------------------------------------------------------
     def timeline(self) -> List[dict]:
@@ -134,9 +100,15 @@ class IOTracer:
 @dataclass
 class StepTimer:
     """Per-step wall-clock decomposition used by the trainer's straggler
-    monitor: how long each step spent waiting on data vs. computing."""
+    monitor: how long each step spent waiting on data vs. computing.
+    ``compute_s`` runs from the step's call to its metrics on the host:
+    ``dispatch_s`` (the jitted call until it returns) plus ``sync_s``
+    (bringing the metrics to the host, which waits for the device) plus the
+    little Python around them."""
 
     data_wait_s: List[float] = field(default_factory=list)
+    dispatch_s: List[float] = field(default_factory=list)
+    sync_s: List[float] = field(default_factory=list)
     compute_s: List[float] = field(default_factory=list)
     checkpoint_s: List[float] = field(default_factory=list)
 
@@ -157,6 +129,8 @@ class StepTimer:
 
         return dict(
             data_wait=stat(self.data_wait_s),
+            dispatch=stat(self.dispatch_s),
+            sync=stat(self.sync_s),
             compute=stat(self.compute_s),
             checkpoint=stat(self.checkpoint_s),
         )

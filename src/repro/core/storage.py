@@ -195,7 +195,7 @@ class NativeStorage(Storage):
         if m:
             _op_metrics("read", self.name, len(data), time.monotonic() - t0)
         if self.tracer:
-            self.tracer.record("read", len(data), path)
+            self.tracer.record("read", len(data))
         return data
 
     def read_range(self, path: str, offset: int, length: int) -> bytes:
@@ -209,7 +209,7 @@ class NativeStorage(Storage):
         if m:
             _op_metrics("read", self.name, len(data), time.monotonic() - t0)
         if self.tracer:
-            self.tracer.record("read", len(data), path)
+            self.tracer.record("read", len(data))
         return data
 
     def write_file(self, path: str, data: bytes, sync: bool = False) -> None:
@@ -226,7 +226,7 @@ class NativeStorage(Storage):
         if m:
             _op_metrics("write", self.name, len(data), time.monotonic() - t0)
         if self.tracer:
-            self.tracer.record("write", len(data), path)
+            self.tracer.record("write", len(data))
 
     def append_file(self, path: str, data: bytes, sync: bool = False) -> None:
         m = metrics.enabled()
@@ -242,7 +242,7 @@ class NativeStorage(Storage):
         if m:
             _op_metrics("write", self.name, len(data), time.monotonic() - t0)
         if self.tracer:
-            self.tracer.record("write", len(data), path)
+            self.tracer.record("write", len(data))
 
     def write_range(self, path: str, offset: int, data: bytes,
                     sync: bool = False) -> None:
@@ -261,7 +261,7 @@ class NativeStorage(Storage):
         if m:
             _op_metrics("write", self.name, len(data), time.monotonic() - t0)
         if self.tracer:
-            self.tracer.record("write", len(data), path)
+            self.tracer.record("write", len(data))
 
     def fsync_dir(self, path: str) -> None:
         ap = self._abs(path)
@@ -438,7 +438,7 @@ class SimulatedStorage(Storage):
         if metrics.enabled():
             _op_metrics("read", self.name, len(data), time.monotonic() - t0)
         if self.tracer:
-            self.tracer.record("read", len(data), path)
+            self.tracer.record("read", len(data))
         return data
 
     def read_range(self, path: str, offset: int, length: int) -> bytes:
@@ -457,7 +457,7 @@ class SimulatedStorage(Storage):
         if metrics.enabled():
             _op_metrics("read", self.name, len(data), time.monotonic() - t0)
         if self.tracer:
-            self.tracer.record("read", len(data), path)
+            self.tracer.record("read", len(data))
         return data
 
     def write_file(self, path: str, data: bytes, sync: bool = False) -> None:
@@ -480,7 +480,7 @@ class SimulatedStorage(Storage):
         if metrics.enabled():
             _op_metrics("write", self.name, len(data), time.monotonic() - t0)
         if self.tracer:
-            self.tracer.record("write", len(data), path)
+            self.tracer.record("write", len(data))
 
     def append_file(self, path: str, data: bytes, sync: bool = False) -> None:
         n = self._enter()
@@ -498,7 +498,7 @@ class SimulatedStorage(Storage):
         if metrics.enabled():
             _op_metrics("write", self.name, len(data), time.monotonic() - t0)
         if self.tracer:
-            self.tracer.record("write", len(data), path)
+            self.tracer.record("write", len(data))
 
     def write_range(self, path: str, offset: int, data: bytes,
                     sync: bool = False) -> None:
@@ -520,7 +520,7 @@ class SimulatedStorage(Storage):
         if metrics.enabled():
             _op_metrics("write", self.name, len(data), time.monotonic() - t0)
         if self.tracer:
-            self.tracer.record("write", len(data), path)
+            self.tracer.record("write", len(data))
 
     def fsync_dir(self, path: str) -> None:
         # Modelled as one seek-class operation.

@@ -30,9 +30,11 @@ Instrumented producers: ``core/storage.py`` (reads/writes, incl. simulated
 device pacing), ``core/dataset.py`` (per-element map/decode),
 ``core/prefetcher.py`` (background fetches + buffer-depth counter),
 ``core/checkpoint.py`` (save/restore), ``core/burst_buffer.py`` (drains),
-``train/trainer.py`` (per-step data-wait vs compute).  ``core.stats.
-IOTracer`` is a thin adapter over :class:`Tracer` for the dstat-style
-timeline view.
+``train/trainer.py`` (per-step data-wait vs compute, and inside the step
+its dispatch and syncs; the save, the stop and the close),
+``core/recovery.py`` (validation, restore, iterator seek on resume) and
+the garbage collector while :func:`start` is in force.  ``core.stats.
+IOTracer`` keeps the dstat-style per-interval byte buckets beside it.
 
 Typical use::
 
@@ -48,15 +50,29 @@ from .tracer import (
     INPUT_PIPELINE_STAGES,
     NULL_SPAN,
     STAGE_CACHE,
+    STAGE_CKPT_BACKPRESSURE,
+    STAGE_CKPT_CLOSE,
     STAGE_CKPT_RESTORE,
+    STAGE_CKPT_SAVE,
     STAGE_CKPT_SNAPSHOT,
+    STAGE_CKPT_VALIDATE,
     STAGE_CKPT_WRITE,
     STAGE_COMPUTE,
     STAGE_DATA_WAIT,
     STAGE_DECODE,
+    STAGE_DEVICE_PREPROCESS,
     STAGE_DRAIN,
+    STAGE_EPOCH_OPEN,
+    STAGE_GC,
+    STAGE_ITERATOR_SEEK,
+    STAGE_PIPELINE_CLOSE,
+    STAGE_PIPELINE_STATE,
+    STAGE_PREEMPT,
+    STAGE_PREEMPT_PROMOTE,
     STAGE_PREFETCH,
     STAGE_STAGE,
+    STAGE_STEP_DISPATCH,
+    STAGE_STEP_SYNC,
     STAGE_STORAGE_READ,
     STAGE_STORAGE_WRITE,
     CounterRecord,
@@ -93,6 +109,11 @@ __all__ = [
     "STAGE_CKPT_RESTORE",
     "STAGE_DRAIN", "STAGE_STAGE", "STAGE_DATA_WAIT", "STAGE_COMPUTE",
     "STAGE_CACHE",
+    "STAGE_STEP_DISPATCH", "STAGE_STEP_SYNC", "STAGE_CKPT_SAVE",
+    "STAGE_PIPELINE_STATE", "STAGE_CKPT_BACKPRESSURE", "STAGE_PREEMPT",
+    "STAGE_PREEMPT_PROMOTE", "STAGE_PIPELINE_CLOSE", "STAGE_CKPT_CLOSE",
+    "STAGE_CKPT_VALIDATE", "STAGE_ITERATOR_SEEK", "STAGE_EPOCH_OPEN",
+    "STAGE_DEVICE_PREPROCESS", "STAGE_GC",
     "INPUT_PIPELINE_STAGES",
     # reports
     "StageStats", "aggregate", "percentile", "overlap_ratio",

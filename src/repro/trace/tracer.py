@@ -22,6 +22,7 @@ at construction/reset), which keeps exported traces small and diff-able.
 """
 from __future__ import annotations
 
+import gc
 import threading
 import time
 from dataclasses import dataclass, field
@@ -44,6 +45,22 @@ STAGE_STAGE = "bb_stage"                  # async-bb fast-tier staging write
 STAGE_DATA_WAIT = "data_wait"             # trainer blocked on next(batch)
 STAGE_COMPUTE = "compute"                 # trainer forward/backward/update
 STAGE_CACHE = "cache"                     # block-cache miss fill / spill I/O
+# Inside the training step, the save, the stop and the resume: each names
+# what the training thread was doing while the device sat idle.
+STAGE_STEP_DISPATCH = "step_dispatch"     # jitted train_step call to return
+STAGE_STEP_SYNC = "step_sync"             # device_get of metrics / step counter
+STAGE_CKPT_SAVE = "ckpt_save"             # Trainer's save, pipeline state incl.
+STAGE_PIPELINE_STATE = "pipeline_state"   # iterator state() for the save meta
+STAGE_CKPT_BACKPRESSURE = "ckpt_backpressure"  # async save waiting for a slot
+STAGE_PREEMPT = "preempt"                 # final save and promote at a stop
+STAGE_PREEMPT_PROMOTE = "preempt_promote"  # engine preempt() / handle.result()
+STAGE_PIPELINE_CLOSE = "pipeline_close"   # Trainer.close of the data iterator
+STAGE_CKPT_CLOSE = "ckpt_close"           # CheckpointManager.close (joins)
+STAGE_CKPT_VALIDATE = "ckpt_validate"     # finding the newest valid step
+STAGE_ITERATOR_SEEK = "iterator_seek"     # restore_state of the data iterator
+STAGE_EPOCH_OPEN = "epoch_open"           # next epoch's pipeline, first element
+STAGE_DEVICE_PREPROCESS = "device_preprocess"  # batched resize+convert call
+STAGE_GC = "gc"                           # a garbage collection (see start())
 
 #: Stages that make up the input pipeline (vs. STAGE_COMPUTE) — the two
 #: interval sets whose overlap is the paper's Fig. 6 observable.
@@ -269,20 +286,64 @@ def get_tracer() -> Optional[Tracer]:
     return _active
 
 
+_gc_hook: Optional["_GcHook"] = None
+
+
+class _GcHook:
+    """``gc.callbacks`` entry that records each collection as a
+    ``STAGE_GC`` span on the thread that triggered it, with the
+    generation in ``args``."""
+
+    def __init__(self, tracer: Tracer):
+        self.tracer = tracer
+        self.t0 = 0.0
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self.t0 = time.monotonic()
+            return
+        tr = self.tracer
+        if not tr.enabled:
+            return
+        th = threading.current_thread()
+        tr._append_span(SpanRecord(
+            stage=STAGE_GC, name="gc", tid=th.ident or 0, thread=th.name,
+            t0=self.t0 - tr._epoch, dur=time.monotonic() - self.t0,
+            args={"generation": info["generation"]}))
+
+
+def _remove_gc_hook() -> None:
+    global _gc_hook
+    if _gc_hook is not None:
+        gc.callbacks.remove(_gc_hook)
+        _gc_hook = None
+
+
 def set_tracer(tracer: Optional[Tracer]) -> Optional[Tracer]:
+    """Install ``tracer`` as the global one (None uninstalls), removing the
+    garbage-collection hook of an earlier :func:`start`."""
     global _active
+    _remove_gc_hook()
     _active = tracer
     return tracer
 
 
 def start(enabled: bool = True) -> Tracer:
-    """Install (and return) a fresh global tracer."""
-    return set_tracer(Tracer(enabled=enabled))
+    """Install (and return) a fresh global tracer, and a ``gc.callbacks``
+    hook that records every garbage collection as a ``STAGE_GC`` span
+    until :func:`stop`."""
+    global _gc_hook
+    tracer = set_tracer(Tracer(enabled=enabled))
+    _gc_hook = _GcHook(tracer)
+    gc.callbacks.append(_gc_hook)
+    return tracer
 
 
 def stop() -> Optional[Tracer]:
-    """Uninstall and return the global tracer (its records stay readable)."""
+    """Uninstall and return the global tracer (its records stay readable)
+    and remove the garbage-collection hook."""
     global _active
+    _remove_gc_hook()
     t, _active = _active, None
     return t
 

@@ -26,8 +26,18 @@ Integrates the paper's pieces end-to-end:
   is promoted to its durability tier within the deadline — the outcome
   lands in :attr:`Trainer.preemption_report`;
 * **straggler monitor**: per-step data-wait vs compute-time is recorded
-  (paper Fig. 6: when prefetch works, data-wait ≈ 0); a sustained data-wait
-  fraction above ``straggler_threshold`` is surfaced in ``report()``.
+  (paper Fig. 6: when prefetch works, data-wait ≈ 0), and compute splits
+  into the step's dispatch and the sync that brings its metrics to the
+  host; a sustained data-wait fraction above ``straggler_threshold`` is
+  surfaced in ``report()``.
+
+Under :mod:`repro.trace` each phase is a span on the training thread:
+``next_batch`` (``STAGE_DATA_WAIT``); ``train_step`` (``STAGE_COMPUTE``)
+holding ``STAGE_STEP_DISPATCH`` and the metrics' ``STAGE_STEP_SYNC``; the
+step counter's own ``STAGE_STEP_SYNC``; ``STAGE_CKPT_SAVE`` holding
+``STAGE_PIPELINE_STATE`` and the engine's spans; at a stop
+``STAGE_PREEMPT`` holding the final save and ``STAGE_PREEMPT_PROMOTE``;
+and ``STAGE_PIPELINE_CLOSE`` in :meth:`Trainer.close`.
 """
 from __future__ import annotations
 
@@ -124,12 +134,20 @@ class Trainer:
                     break
             t1 = time.monotonic()
             with trace.span(trace.STAGE_COMPUTE, "train_step"):
-                self.state, metrics = self.train_step(self.state, batch)
-                metrics = {k: float(jax.device_get(v)) for k, v in metrics.items()}
+                with trace.span(trace.STAGE_STEP_DISPATCH, "train_step"):
+                    self.state, metrics = self.train_step(self.state, batch)
+                t_dispatched = time.monotonic()
+                with trace.span(trace.STAGE_STEP_SYNC, "metrics"):
+                    metrics = {k: float(jax.device_get(v))
+                               for k, v in metrics.items()}
+                t_synced = time.monotonic()
             t2 = time.monotonic()
             self.timer.data_wait_s.append(t1 - t0)
+            self.timer.dispatch_s.append(t_dispatched - t1)
+            self.timer.sync_s.append(t_synced - t_dispatched)
             self.timer.compute_s.append(t2 - t1)
-            step = self.step
+            with trace.span(trace.STAGE_STEP_SYNC, "step_counter"):
+                step = self.step
             metrics["step"] = step
             self.history.append(metrics)
             # live heartbeat: the paper's Fig. 6 observable, per step
@@ -152,17 +170,11 @@ class Trainer:
             if self._stop_requested:
                 if self.checkpointer is not None:
                     t_pre = time.monotonic()
-                    handle = self._save_checkpoint(step)
-                    preempt = getattr(self.checkpointer, "preempt", None)
-                    if callable(preempt):
-                        # graceful-shutdown budget: promote the newest
-                        # in-flight save (this one) within the deadline,
-                        # abandon older queued snapshots
-                        self.preemption_report = preempt(
-                            self._preempt_deadline_s)
-                    elif handle is not None:
-                        # preemption save must be durable before we stop
-                        handle.result()
+                    with trace.span(trace.STAGE_PREEMPT, "preempt"):
+                        handle = self._save_checkpoint(step)
+                        with trace.span(trace.STAGE_PREEMPT_PROMOTE,
+                                        "preempt_promote"):
+                            self._promote(handle)
                     self.preempt_s = time.monotonic() - t_pre
                 break
         # surface any background write failure that settled during the run
@@ -171,6 +183,16 @@ class Trainer:
         return self.history
 
     # -- checkpointing --------------------------------------------------------
+    def _promote(self, handle) -> None:
+        """Make the preemption save durable before the stop."""
+        preempt = getattr(self.checkpointer, "preempt", None)
+        if callable(preempt):
+            # graceful-shutdown budget: promote the newest in-flight save
+            # (this one) within the deadline, abandon older queued snapshots
+            self.preemption_report = preempt(self._preempt_deadline_s)
+        elif handle is not None:
+            handle.result()
+
     def _save_checkpoint(self, step: int):
         """Save; returns the async handle if the checkpointer is async.
 
@@ -180,14 +202,17 @@ class Trainer:
         blocked time."""
         self._reap_saves()
         t3 = time.monotonic()
-        extra = None
-        state_fn = getattr(self.data_iter, "state", None)
-        if callable(state_fn):
-            # iterator checkpoint rides along in the meta (tf.data-style),
-            # captured on the training thread so it is consistent with the
-            # params being saved even under an async engine
-            extra = {"pipeline": state_fn()}
-        result = self.checkpointer.save(step, self.state, extra_meta=extra)
+        with trace.span(trace.STAGE_CKPT_SAVE, "ckpt_save"):
+            extra = None
+            state_fn = getattr(self.data_iter, "state", None)
+            if callable(state_fn):
+                # iterator checkpoint rides along in the meta (tf.data-style),
+                # captured on the training thread so it is consistent with
+                # the params being saved even under an async engine
+                with trace.span(trace.STAGE_PIPELINE_STATE, "pipeline_state"):
+                    extra = {"pipeline": state_fn()}
+            result = self.checkpointer.save(step, self.state,
+                                            extra_meta=extra)
         self.timer.checkpoint_s.append(time.monotonic() - t3)
         if hasattr(result, "done") and hasattr(result, "exception"):
             self._pending_saves.append(result)
@@ -226,7 +251,8 @@ class Trainer:
         this (or rely on GC) to stop the background producer promptly."""
         close = getattr(self.data_iter, "close", None)
         if close is not None:
-            close()
+            with trace.span(trace.STAGE_PIPELINE_CLOSE, "pipeline_close"):
+                close()
 
     # -- diagnostics ---------------------------------------------------------
     def report(self) -> Dict[str, Any]:

@@ -1,4 +1,4 @@
-"""IOTracer adapter (dstat view over repro.trace) + kind validation."""
+"""IOTracer (dstat view: per-interval byte buckets) + kind validation."""
 import time
 
 import pytest
@@ -19,7 +19,7 @@ class TestKindValidation:
     @pytest.mark.parametrize("kind", ["read", "write"])
     def test_valid_kinds_accepted(self, kind):
         tr = IOTracer()
-        tr.record(kind, 100, "f")
+        tr.record(kind, 100)
         t = tr.totals()
         assert t[f"{kind}_bytes"] == 100
         assert t[f"{kind}_ops"] == 1
@@ -28,10 +28,10 @@ class TestKindValidation:
 class TestAdapter:
     def test_totals_and_timeline(self):
         tr = IOTracer(interval_s=0.05)
-        tr.record("read", 1000, "a")
-        tr.record("write", 500, "b")
+        tr.record("read", 1000)
+        tr.record("write", 500)
         time.sleep(0.06)
-        tr.record("read", 2000, "c")
+        tr.record("read", 2000)
         t = tr.totals()
         assert t == dict(read_bytes=3000, write_bytes=500,
                          read_ops=2, write_ops=1)
@@ -50,36 +50,11 @@ class TestAdapter:
 
     def test_reset(self):
         tr = IOTracer()
-        tr.record("read", 10)
+        for _ in range(100):
+            tr.record("read", 10)
+        # ops fold into per-interval buckets: no per-op records retained
+        assert len(tr._buckets) == 1
+        assert tr.totals()["read_ops"] == 100
         tr.reset()
         assert tr.timeline() == []
         assert tr.totals()["read_ops"] == 0
-
-    def test_events_gated_by_keep_events(self):
-        tr = IOTracer()
-        tr.record("read", 10, "x")   # keep_events off: not logged
-        assert tr.events == []
-        tr.keep_events = True
-        tr.record("write", 20, "y")
-        kinds = [(k, n, tag) for _t, k, n, tag in tr.events]
-        assert kinds == [("write", 20, "y")]
-        # the bucketed view saw both ops regardless
-        assert tr.totals()["read_ops"] == 1 and tr.totals()["write_ops"] == 1
-
-    def test_collector_exposed_for_span_tooling(self):
-        from repro import trace
-
-        tr = IOTracer()
-        tr.keep_events = True
-        tr.record("read", 64, "f.bin")
-        spans = tr.collector.spans()
-        assert spans[0].stage == trace.STAGE_STORAGE_READ
-        assert spans[0].nbytes == 64
-
-    def test_bounded_memory_without_keep_events(self):
-        # default mode folds into buckets: no per-op records retained
-        tr = IOTracer()
-        for _ in range(100):
-            tr.record("read", 1)
-        assert tr.collector.spans() == []
-        assert tr.totals()["read_ops"] == 100
