@@ -29,7 +29,6 @@ Guarantees:
 """
 from __future__ import annotations
 
-import io
 import json
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -260,7 +259,15 @@ class CheckpointSaver:
         return result
 
     def _serialize(self, flat: Dict[str, np.ndarray]):
-        """Pack tensors into per-shard byte buffers + the tensor index."""
+        """Pack tensors into one exact-size ``uint8`` buffer per shard, plus
+        the tensor index.
+
+        Every size is known before a byte is copied, so each shard is one
+        allocation, and each tensor reaches it by a numpy copy, which
+        releases the interpreter lock: the training thread keeps running
+        beside a background save.  The bytes are those of ``tobytes()``: C
+        order, tensors back to back.
+        """
         # Assign tensors to shards round-robin by size (largest first) so the
         # N writer hosts carry balanced bytes.
         names = sorted(flat, key=lambda k: -flat[k].nbytes)
@@ -271,15 +278,15 @@ class CheckpointSaver:
             shard_of[name] = s
             shard_bytes[s] += flat[name].nbytes
 
-        buffers = [io.BytesIO() for _ in range(self.n_shards)]
+        sizes = [0] * self.n_shards
+        parts: List[Tuple[int, int, np.ndarray]] = []  # (shard, offset, array)
         index: Dict[str, dict] = {}
         for name in flat:
             arr = flat[name]
             s = shard_of[name]
-            buf = buffers[s]
             entry: Dict[str, Any] = dict(
                 shard=s,
-                offset=buf.tell(),
+                offset=sizes[s],
                 shape=list(arr.shape),
                 dtype=str(arr.dtype),
             )
@@ -287,18 +294,23 @@ class CheckpointSaver:
                     and str(arr.dtype) in _QUANTIZABLE_DTYPES
                     and arr.size >= _QBLOCK):
                 q, scale, pad = quantize_blockwise(arr)
-                buf.write(q.tobytes())
                 entry.update(
                     quant="int8", qpad=pad, qblock=_QBLOCK,
-                    scale_offset=buf.tell(), scale_len=scale.nbytes,
+                    scale_offset=sizes[s] + q.nbytes, scale_len=scale.nbytes,
                 )
-                buf.write(scale.tobytes())
-                entry["length"] = buf.tell() - entry["offset"]
+                pieces = (q, scale)
             else:
-                data = arr.tobytes()
-                buf.write(data)
-                entry["length"] = len(data)
+                pieces = (arr,)
+            for piece in pieces:
+                parts.append((s, sizes[s], piece))
+                sizes[s] += piece.nbytes
+            entry["length"] = sizes[s] - entry["offset"]
             index[name] = entry
+
+        buffers = [np.empty(n, np.uint8) for n in sizes]
+        for s, offset, piece in parts:
+            dst = buffers[s][offset:offset + piece.nbytes]
+            dst.view(piece.dtype).reshape(piece.shape)[...] = piece
         return buffers, index
 
     def _save_flat(self, step: int, flat: Dict[str, np.ndarray],
@@ -306,7 +318,10 @@ class CheckpointSaver:
                    treedef=None) -> SaveResult:
         t0 = time.monotonic()
         base = self._base(step)
-        buffers, index = self._serialize(flat)
+        with trace.span(trace.STAGE_CKPT_SERIALIZE,
+                        f"serialize:{base}") as sp:
+            buffers, index = self._serialize(flat)
+            sp.set_bytes(sum(buf.nbytes for buf in buffers))
 
         files: List[str] = []
         total = 0
@@ -316,9 +331,9 @@ class CheckpointSaver:
             f"{base}.data-{s:05d}-of-{self.n_shards:05d}"
             for s in range(self.n_shards)
         ]
-        # getbuffer(): zero-copy views — getvalue() would transiently double
-        # peak host memory on a multi-GB checkpoint
-        shard_blobs = [buf.getbuffer() for buf in buffers]
+        # zero-copy views — bytes(buf) would transiently double peak host
+        # memory on a multi-GB checkpoint
+        shard_blobs = [memoryview(buf) for buf in buffers]
         if self.io_threads > 1 and self.n_shards > 1:
             with ThreadPoolExecutor(
                 min(self.io_threads, self.n_shards),

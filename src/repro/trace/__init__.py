@@ -54,6 +54,7 @@ from .tracer import (
     STAGE_CKPT_CLOSE,
     STAGE_CKPT_RESTORE,
     STAGE_CKPT_SAVE,
+    STAGE_CKPT_SERIALIZE,
     STAGE_CKPT_SNAPSHOT,
     STAGE_CKPT_VALIDATE,
     STAGE_CKPT_WRITE,
@@ -106,7 +107,7 @@ __all__ = [
     # stages
     "STAGE_STORAGE_READ", "STAGE_STORAGE_WRITE", "STAGE_DECODE",
     "STAGE_PREFETCH", "STAGE_CKPT_SNAPSHOT", "STAGE_CKPT_WRITE",
-    "STAGE_CKPT_RESTORE",
+    "STAGE_CKPT_SERIALIZE", "STAGE_CKPT_RESTORE",
     "STAGE_DRAIN", "STAGE_STAGE", "STAGE_DATA_WAIT", "STAGE_COMPUTE",
     "STAGE_CACHE",
     "STAGE_STEP_DISPATCH", "STAGE_STEP_SYNC", "STAGE_CKPT_SAVE",

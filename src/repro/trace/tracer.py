@@ -38,6 +38,7 @@ STAGE_DECODE = "decode"                   # Dataset.map fn (read+decode+resize)
 STAGE_PREFETCH = "prefetch"               # background prefetch-thread fetch
 STAGE_CKPT_SNAPSHOT = "checkpoint_snapshot"  # pytree -> host memory (blocking)
 STAGE_CKPT_WRITE = "checkpoint_write"     # CheckpointSaver.save (serialize+write)
+STAGE_CKPT_SERIALIZE = "checkpoint_serialize"  # tensors -> shard buffers
 STAGE_CKPT_RESTORE = "checkpoint_restore" # CheckpointSaver.restore
 STAGE_DRAIN = "bb_drain"                  # burst-buffer background drain
 STAGE_STAGE = "bb_stage"                  # async-bb fast-tier staging write

@@ -134,6 +134,7 @@ class TestBlockedTime:
         stages = {s.stage for s in spans}
         assert trace.STAGE_CKPT_SNAPSHOT in stages
         assert trace.STAGE_CKPT_WRITE in stages
+        assert trace.STAGE_CKPT_SERIALIZE in stages
         ov = trace.overlap_ratio(
             spans, fg_stages=(trace.STAGE_CKPT_WRITE,),
             bg_stages=(trace.STAGE_COMPUTE,))

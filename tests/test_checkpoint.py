@@ -1,4 +1,6 @@
-"""Checkpoint saver: roundtrip, retention, atomic commit, int8, elastic."""
+"""Checkpoint saver: roundtrip, retention, atomic commit, int8, elastic,
+and the shard format byte for byte."""
+import io
 import json
 
 import numpy as np
@@ -12,6 +14,8 @@ except ModuleNotFoundError:  # bare env (see `test` extra in pyproject.toml)
 from repro.core.checkpoint import (
     CheckpointSaver, dequantize_blockwise, quantize_blockwise, resolve_dtype,
 )
+from repro.core.recovery import CheckpointManager
+from repro.core.storage import NativeStorage
 
 
 def tree():
@@ -157,3 +161,117 @@ class TestElastic:
         sh = {"w": NamedSharding(mesh, P("data", None))}
         out = saver.restore_sharded(t, sh)
         np.testing.assert_array_equal(np.asarray(out["w"]), t["w"])
+
+
+# ---------------------------------------------------------------------------
+# the shard format, byte for byte
+# ---------------------------------------------------------------------------
+def format_leaves():
+    """One leaf of each layout the serializer has to lay out as
+    ``tobytes()`` does; the float ones hold 256 elements or more, so int8
+    quantizes them (with a padded last block where the size asks for it)."""
+    import ml_dtypes
+
+    rng = np.random.default_rng(7)
+    wide = rng.normal(size=(40, 60)).astype(np.float32)
+    leaves = {
+        "f32": rng.normal(size=(19, 31)).astype(np.float32),
+        "bf16": rng.normal(size=(300,)).astype(ml_dtypes.bfloat16),
+        "int32": rng.integers(-2**31, 2**31 - 1, size=(7, 45), dtype=np.int32),
+        "scalar": np.array(3.5, np.float32),
+        "empty": np.zeros((0, 5), np.float32),
+        "fortran": np.asfortranarray(
+            rng.normal(size=(24, 33)).astype(np.float32)),
+        "strided": wide[::2, ::3],
+    }
+    assert not leaves["fortran"].flags["C_CONTIGUOUS"]
+    assert not leaves["strided"].flags["C_CONTIGUOUS"]
+    return leaves
+
+
+def tobytes_shards(flat, n_shards, quantize):
+    """The format as ``tobytes()`` into one ``BytesIO`` per shard wrote it:
+    the reference the serializer's shards and index must equal."""
+    names = sorted(flat, key=lambda k: -flat[k].nbytes)
+    shard_of, shard_bytes = {}, [0] * n_shards
+    for name in names:
+        s = int(np.argmin(shard_bytes))
+        shard_of[name] = s
+        shard_bytes[s] += flat[name].nbytes
+    buffers = [io.BytesIO() for _ in range(n_shards)]
+    index = {}
+    for name, arr in flat.items():
+        buf = buffers[shard_of[name]]
+        entry = dict(shard=shard_of[name], offset=buf.tell(),
+                     shape=list(arr.shape), dtype=str(arr.dtype))
+        if (quantize == "int8" and str(arr.dtype) in
+                ("float32", "float64", "bfloat16") and arr.size >= 256):
+            q, scale, pad = quantize_blockwise(arr)
+            buf.write(q.tobytes())
+            entry.update(quant="int8", qpad=pad, qblock=256,
+                         scale_offset=buf.tell(), scale_len=scale.nbytes)
+            buf.write(scale.tobytes())
+            entry["length"] = buf.tell() - entry["offset"]
+        else:
+            data = arr.tobytes()
+            buf.write(data)
+            entry["length"] = len(data)
+        index[name] = entry
+    return [b.getvalue() for b in buffers], index
+
+
+FORMAT_CASES = ["f32", "bf16", "int32", "scalar", "empty", "fortran",
+                "strided", "all"]
+
+
+class TestShardFormat:
+    @pytest.mark.parametrize("quantize", [None, "int8"])
+    @pytest.mark.parametrize("n_shards", [1, 3])
+    @pytest.mark.parametrize("leaf", FORMAT_CASES)
+    def test_shards_and_index_equal_tobytes_layout(self, tmp_storage, leaf,
+                                                   n_shards, quantize):
+        leaves = format_leaves()
+        flat = leaves if leaf == "all" else {leaf: leaves[leaf]}
+        want_shards, want_index = tobytes_shards(flat, n_shards, quantize)
+        saver = CheckpointSaver(tmp_storage, "ckpt/m", n_shards=n_shards,
+                                quantize=quantize, sync=False)
+        result = saver.save_flat(1, flat)
+        got = [tmp_storage.read_file(f"ckpt/m-1.data-{s:05d}-of-{n_shards:05d}")
+               for s in range(n_shards)]
+        assert got == want_shards
+        index_blob = json.dumps(
+            dict(tensors=want_index, n_shards=n_shards)).encode()
+        assert tmp_storage.read_file("ckpt/m-1.index") == index_blob
+        meta_len = len(tmp_storage.read_file("ckpt/m-1.meta"))
+        assert result.n_bytes == (sum(map(len, want_shards))
+                                  + len(index_blob) + meta_len)
+
+    @pytest.mark.parametrize("engine", ["direct", "async", "bb", "asyncbb"])
+    def test_manager_resume_is_bit_exact(self, engine, tmp_path):
+        leaves = format_leaves()
+        state = {"params": {k: v for k, v in leaves.items()
+                            if k not in ("scalar", "int32")},
+                 "step": leaves["scalar"], "rng": leaves["int32"]}
+        slow = NativeStorage(str(tmp_path / "slow"))
+        fast = NativeStorage(str(tmp_path / "fast"))
+        mgr = CheckpointManager(slow, "ckpt/m", engine=engine,
+                                fast_storage=fast, n_shards=3)
+        mgr.save(4, state)
+        mgr.wait()
+        mgr.close()
+        skeleton = {"params": {k: np.zeros_like(v)
+                               for k, v in state["params"].items()},
+                    "step": np.zeros_like(state["step"]),
+                    "rng": np.zeros_like(state["rng"])}
+        fresh = CheckpointManager(slow, "ckpt/m", n_shards=3)
+        res = fresh.resume(skeleton)
+        fresh.close()
+        assert res.step == 4
+        want = dict(state["params"], step=state["step"], rng=state["rng"])
+        got = dict(res.state["params"], step=res.state["step"],
+                   rng=res.state["rng"])
+        assert set(got) == set(want)
+        for name, arr in want.items():
+            out = np.asarray(got[name])
+            assert out.dtype == arr.dtype and out.shape == arr.shape, name
+            assert out.tobytes() == arr.tobytes(), name

@@ -326,6 +326,7 @@ class TestInstrumentation:
         assert trace.STAGE_DECODE in stages
         assert trace.STAGE_PREFETCH in stages
         assert trace.STAGE_CKPT_WRITE in stages
+        assert trace.STAGE_CKPT_SERIALIZE in stages
         assert trace.STAGE_CKPT_RESTORE in stages
         # read bytes attributed
         reads = [r for r in tr.spans() if r.stage == trace.STAGE_STORAGE_READ]
@@ -351,6 +352,41 @@ class TestInstrumentation:
         assert len(drains) == 1
         assert drains[0].nbytes > 0
         assert "drain:ckpt/m-1" in drains[0].name
+
+    @pytest.mark.parametrize("engine", ["direct", "async", "bb", "asyncbb"])
+    def test_one_serialize_span_inside_each_save(self, engine, tmp_path):
+        """Every engine serializes once per save, inside the ``save:`` span
+        of the same thread, and the span carries the shard bytes packed."""
+        import numpy as np
+
+        from repro.core.recovery import CheckpointManager
+        from repro.core.storage import NativeStorage
+
+        slow = NativeStorage(str(tmp_path / "slow"))
+        fast = NativeStorage(str(tmp_path / "fast"))
+        state = {"w": np.ones((64, 8), np.float32), "b": np.arange(8),
+                 "s": np.float32(2)}
+        tr = trace.start()
+        try:
+            mgr = CheckpointManager(slow, "ckpt/m", engine=engine,
+                                    fast_storage=fast, n_shards=3,
+                                    sync=False)
+            mgr.save(1, state)
+            mgr.wait()
+            mgr.close()
+        finally:
+            trace.stop()
+        spans = tr.spans()
+        ser = [r for r in spans if r.stage == trace.STAGE_CKPT_SERIALIZE]
+        saves = [r for r in spans if r.stage == trace.STAGE_CKPT_WRITE]
+        assert len(ser) == 1 and len(saves) == 1
+        (sv,), (sp,) = saves, ser
+        assert sv.name.startswith("save:") and sp.tid == sv.tid
+        assert sv.t0 <= sp.t0 and sp.t0 + sp.dur <= sv.t0 + sv.dur + 1e-9
+        shard_bytes = sum(len(slow.read_file(f"ckpt/m-1.data-{i:05d}-of-00003"))
+                          for i in range(3))
+        assert sp.nbytes == shard_bytes == sum(
+            np.asarray(v).nbytes for v in state.values())
 
     def test_untraced_by_default(self, tmp_storage):
         tmp_storage.write_file("b.bin", b"q")
