@@ -2,7 +2,7 @@
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-Set-up (corpus, weights, pipeline, trainer, the first steps that compile),
+Set-up (corpus, state, pipeline, trainer, the first steps that compile),
 then ``--seconds`` of measured window, then the saves still draining, then
 the check against the reference.  With ``--trace 0`` the result carries the
 cell's end-to-end metrics, with ``--trace 1`` its per-layer metrics, read
@@ -23,12 +23,11 @@ import time
 from typing import Dict, Optional
 
 import jax
-import numpy as np
 from jax import monitoring
 
 from repro import trace as program_trace
 
-from . import check, flops, trace_reduce
+from . import check, trace_reduce
 from .loop import CellRun
 from .peaks import peaks_for
 from .spec import Spec
@@ -82,15 +81,15 @@ def parse(argv=None):
 def record(run: CellRun, *, setup_s: float, peaks, tracer=None,
            t_epoch: Optional[float] = None, device: Optional[Dict] = None):
     """What the metric readers read: the window's steps, saves, commits and
-    resumes on the host clock, the program's spans, the trace's device
-    numbers, and the counts of work computed from shapes."""
+    resumes on the host clock, the program's spans and counters, the trace's
+    device numbers, and the counts of work that the architecture's module
+    computes from shapes."""
     steps = run.in_window()
     saved, committed = run.ckpt_log.saved, run.ckpt_log.committed
     periodic = run.periodic_saves()
     missing = [s for s in periodic if s not in committed]
     if missing:
         raise RuntimeError(f"saves {missing} never committed to the slow tier")
-    model, corpus = run.model, run.cfg
     rec = {
         "window_s": run.t_w1 - run.t_w0,
         "batch": run.batch,
@@ -108,20 +107,12 @@ def record(run: CellRun, *, setup_s: float, peaks, tracer=None,
         "resumes": [{"s": r.t_end - r.t_begin,
                      "restore_s": r.restored.restore_s}
                     for r in run.resumes if r.t_end <= run.t_w1],
-        "train_flops_per_image": flops.alexnet_train_flops(model),
-        "resize": {
-            "flops": flops.resize_flops(run.batch, corpus["image_hw"],
-                                        corpus["image_hw"], model["channels"],
-                                        model["in_hw"], model["in_hw"]),
-            "bytes": flops.resize_bytes(run.batch, corpus["image_hw"],
-                                        corpus["image_hw"], model["channels"],
-                                        model["in_hw"], model["in_hw"])},
         "peak_flops": peaks.bf16_flops,
         "peak_bytes_s": peaks.hbm_bytes_s,
-        "uses_resize_kernel": run.traffic["batched_preprocess"] == "pallas",
         "device": device,
         "spans": None,
     }
+    rec.update(run.arch.counts(run.cfg, run.traffic, run.batch))
     if tracer is not None:
         lo, hi = run.t_w0 - t_epoch, run.t_w1 - t_epoch
         spans = [s for s in tracer.spans() if lo <= s.t0 <= hi]
@@ -131,40 +122,44 @@ def record(run: CellRun, *, setup_s: float, peaks, tracer=None,
             "drain_s": [s.dur for s in spans
                         if s.stage == program_trace.STAGE_DRAIN],
         }
+        # every span and counter of the window, times from its start
+        rec["program_spans"] = [
+            {"stage": s.stage, "name": s.name, "thread": s.thread,
+             "t0": s.t0 - lo, "dur": s.dur, "nbytes": s.nbytes,
+             "args": s.args} for s in spans]
+        rec["program_counters"] = [
+            {"name": c.name, "t": c.t - lo, "value": c.value}
+            for c in tracer.counters() if lo <= c.t <= hi]
     return rec
 
 
 def judge(run: CellRun, keep_reference: bool = False):
     """The numbers ``correct`` compares.  The program's arrays come to the
     host and its device state is freed before the reference runs.  With
-    ``keep_reference``, also returns the reference's batches and
-    trajectory, for the readings of the control and the faults."""
-    host_batches = [tuple(np.asarray(jax.device_get(x)) for x in b)
-                    for b in run.first_batches]
-    seen = [check.to_host(p) for p in run.params_seen]
+    ``keep_reference``, also returns the program's and the reference's
+    trajectories, the reference's batches and the program's, for the
+    readings of the control and the faults."""
+    arch, model = run.arch, run.model
+    host_batches = [jax.device_get(b) for b in run.first_batches]
+    states = run.states_seen
+    prog = (list(run.first_losses), check.to_host(states[0]["params"]),
+            arch.first_gradient(states[0], states[1], model),
+            check.to_host(states[-1]["params"]))
     resumes = [(check.state_mismatches(r.saved_state, r.restored.state),
                 check.batches_differ(r.next_batch, r.first_batch))
                for r in run.resumes]
-    losses = list(run.first_losses)
-    run.first_batches = run.params_seen = run.resumes = None
+    run.first_batches = run.states_seen = run.resumes = states = None
     gc.collect()
-    model = run.model
-    ref_images, ref_labels, rows_wrong, pixel_gap, records = \
-        check.reference_batches(run.corpus, host_batches, model["in_hw"])
-    ref = check.reference_steps(run.seed, model, ref_images, ref_labels,
-                                run.devices[0])
-    ref_losses, rp0, rg1, _, rpn = ref
-    numbers = {"rows_wrong": rows_wrong, "pixel_gap": pixel_gap}
-    numbers.update(check.training_gaps(losses, seen[0], seen[1], seen[2],
-                                       ref_losses, rp0, rg1, rpn,
-                                       model["lr"]))
+    numbers, ref_batches = arch.check_batches(run.corpus, host_batches, model)
+    ref = arch.reference_steps(run.seed, model, ref_batches, run.devices[0])
+    numbers.update(check.training_gaps(prog, ref))
     if run.traffic.get("preempt"):
         numbers["restore_wrong"] = sum(n for n, _ in resumes)
         # a window that held no resume has checked none
         numbers["position_wrong"] = (sum(1 for _, d in resumes if d)
                                      if resumes else 1)
     if keep_reference:
-        return numbers, (ref_images, ref_labels, ref, host_batches, records)
+        return numbers, (prog, ref, ref_batches, host_batches)
     return numbers
 
 
@@ -211,11 +206,12 @@ def main(argv=None, *, t_start: Optional[float] = None, spec: Spec = None,
     devices = devices[:cell["chips"]]
     peaks = peaks or peaks_for(devices[0].device_kind)
     cfg, traffic = spec.config(cell["config"]), spec.traffic(cell["traffic"])
+    arch = spec.arch(cfg["model"]["arch"])
     compiles = CompileLog()
     workdir = tempfile.mkdtemp(prefix="bench-")
     trace_dir = spec.root / TRACE_DIR / args.workload
     try:
-        run = CellRun(cfg, traffic, args.seed, devices, workdir,
+        run = CellRun(cfg, traffic, args.seed, devices, workdir, arch,
                       make_train_step=make_train_step)
         run.setup()
         tracer = t_epoch = t_trace = None

@@ -1,10 +1,12 @@
 """One run of one cell: set-up, the measured window, and what it leaves.
 
-The traffic file says what the window does: the input pipeline's
-parameters (``"batched_preprocess"`` and the rest of
-``sharded_image_pipeline``'s), the checkpoint cadence (``"ckpt"``) and the
-preemptions (``"preempt"``).  This one loop serves every traffic file; a new
-mix is a new file.
+The traffic file says what the window does: the input's parameters (which
+the architecture's module reads), the checkpoint cadence (``"ckpt"``) and
+the preemptions (``"preempt"``: the first step, the steps between two, and
+with ``"count"`` how many there are at most).  The model, its data and its
+step come from the architecture's module (``bench/arch/<arch>.py``).  This
+one loop serves every traffic file and every architecture; a new one is a
+new file.
 """
 from __future__ import annotations
 
@@ -14,28 +16,20 @@ from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 
-from repro.configs.alexnet_mini import AlexNetConfig
-from repro.core import (CheckpointManager, ResumableIterator, make_storage,
-                        sharded_image_pipeline)
-from repro.models import alexnet
+from repro.core import CheckpointManager, ResumableIterator, make_storage
 from repro.train.trainer import Trainer
 
-from . import hooks, weights
-from .corpus import Corpus, build_corpus
+from . import hooks
 
 FIRST_STEPS = 3        # set-up steps, the ones the reference follows
 CHUNK = 25             # steps per Trainer.run call between deadline checks
-CKPT_PREFIX = "ckpt/alexnet"
 
 
-def program_config(model: dict) -> AlexNetConfig:
-    return AlexNetConfig(name="bench", in_hw=model["in_hw"],
-                         channels=model["channels"],
-                         n_classes=model["n_classes"],
-                         filters=tuple(model["filters"]),
-                         fc=tuple(model["fc"]), lr=model["lr"])
+def _kept(state: dict) -> dict:
+    """What the check needs of a state: all of it but the step counter,
+    which would stay on the device beside it for nothing."""
+    return {k: v for k, v in state.items() if k != "step"}
 
 
 @dataclass
@@ -68,14 +62,15 @@ class Segment:
 
 class CellRun:
     def __init__(self, cfg: dict, traffic: dict, seed: int, devices: list,
-                 workdir: str, make_train_step=None):
+                 workdir: str, arch, make_train_step=None):
         self.cfg, self.traffic, self.seed = cfg, traffic, seed
+        self.arch = arch
         self.model = cfg["model"]
         self.batch = cfg["batch"]
         self.devices = devices
         self.workdir = workdir
-        make = make_train_step or alexnet.make_train_step
-        self.train_step = make(program_config(self.model))
+        make = make_train_step or arch.make_train_step
+        self.train_step = make(self.model, devices)
         self.ckpt_log = hooks.CheckpointLog()
         self.steps: List[StepRecord] = []
         self.resumes: List[Resume] = []
@@ -83,26 +78,14 @@ class CellRun:
         self.preempts: List[Tuple[float, float]] = []  # the save and stop
         self.cur = 0
         self.seg: Optional[Segment] = None
-        self.corpus: Optional[Corpus] = None
+        self.corpus = self.epoch = None
         ckpt, pre = traffic.get("ckpt"), traffic.get("preempt")
         self.ckpt_every = ckpt["every_steps"] if ckpt else 0
         self.next_preempt = pre["first_step"] if pre else None
 
     # -- building blocks -----------------------------------------------------
     def _pipeline(self, keep: int = 0):
-        t, c = self.traffic, self.corpus
-        hw = self.model["in_hw"]
-
-        def epoch(ep):
-            return sharded_image_pipeline(
-                c.storage, c.paths, c.labels_per_shard, batch_size=self.batch,
-                cycle_length=t["cycle_length"], block_length=t["block_length"],
-                num_parallel_calls=t["num_parallel_calls"],
-                prefetch=t["prefetch"], out_hw=(hw, hw),
-                batched_preprocess=t["batched_preprocess"], seed=ep,
-                repeat=False)
-
-        data = ResumableIterator(epoch)
+        data = ResumableIterator(self.epoch)
         return data, hooks.Feed(data, keep)
 
     def _manager(self):
@@ -111,7 +94,8 @@ class CellRun:
             return None
         slow = make_storage("native", os.path.join(self.workdir, "slow"))
         fast = make_storage("native", os.path.join(self.workdir, "fast"))
-        mgr = CheckpointManager(slow, CKPT_PREFIX, engine=ckpt["engine"],
+        mgr = CheckpointManager(slow, f"ckpt/{self.model['arch']}",
+                                engine=ckpt["engine"],
                                 fast_storage=fast,
                                 max_pending=ckpt["max_pending"])
         return hooks.Checkpoints(mgr, self.ckpt_log)
@@ -146,23 +130,25 @@ class CellRun:
 
     # -- set-up --------------------------------------------------------------
     def setup(self) -> None:
-        """Corpus, weights, pipeline, trainer; then the first steps through
+        """Corpus, state, pipeline, trainer; then the first steps through
         the window's own call and feed, which compile every program the
-        window runs.  Keeps what the reference needs of them."""
+        window runs.  Keeps what the reference needs of them: the states
+        before the first step, after it and after the last."""
         t0 = time.monotonic()
-        self.corpus = build_corpus(self.cfg, self.seed,
-                                   os.path.join(self.workdir, "corpus"))
+        self.corpus = self.arch.build_corpus(
+            self.cfg, self.seed, os.path.join(self.workdir, "corpus"))
+        self.epoch = self.arch.epoch_factory(self.corpus, self.cfg,
+                                             self.traffic, self.batch)
         t1 = time.monotonic()
-        params = weights.make_params(self.seed, self.model)
-        state = {"params": params, "step": jnp.int32(0)}
+        state = self.arch.make_state(self.seed, self.model, self.devices)
         self.skeleton = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state)
         self.seg = self._segment(state, resume=False, keep=FIRST_STEPS)
-        self.params_seen = [params]
+        self.states_seen = [_kept(state)]
         self.seg.trainer.run(1)
-        self.params_seen.append(self.seg.trainer.state["params"])
+        self.states_seen.append(_kept(self.seg.trainer.state))
         self.seg.trainer.run(FIRST_STEPS - 1)
-        self.params_seen.append(self.seg.trainer.state["params"])
+        self.states_seen.append(_kept(self.seg.trainer.state))
         self.first_losses = [h["loss"] for h in self.seg.trainer.history]
         self.first_batches = list(self.seg.feed.kept)
         jax.block_until_ready(self.seg.trainer.state)
@@ -174,12 +160,14 @@ class CellRun:
     def window(self, seconds: float) -> None:
         self.t_w0 = time.monotonic()
         self.t_w1 = self.t_w0 + seconds
-        every = (self.traffic.get("preempt") or {}).get("every_steps")
+        pre = self.traffic.get("preempt") or {}
         while time.monotonic() < self.t_w1:
             if self.next_preempt is not None \
                     and self.cur + 1 >= self.next_preempt:
                 self._preempt_and_resume()
-                self.next_preempt += every
+                self.next_preempt += pre["every_steps"]
+                if len(self.preempt_steps) == pre.get("count"):
+                    self.next_preempt = None
                 continue
             n = CHUNK
             if self.next_preempt is not None:

@@ -3,15 +3,20 @@
 * a configuration: the ``file`` its entry names;
 * a traffic mix: ``<paths[0]>/traffic/<traffic>.json``;
 * a metric: ``<paths[0]>/metrics/<name>.py``, whose ``read(record)``
-  returns the metric's value, or ``None`` where the run has nothing to read.
+  returns the metric's value, or ``None`` where the run has nothing to read;
+* an architecture: ``<paths[0]>/arch/<arch>.py``, named by the
+  configuration's ``model.arch``: everything a run needs that depends on the
+  model and its data (corpus, input pipeline, state, step, work counts, the
+  input's check and the plain reference; see ``arch/alexnet.py``).
 
-A later cell, configuration, traffic mix or metric is a new file and a new
-entry; nothing here changes.
+A later cell, configuration, traffic mix, metric or architecture is a new
+file and a new entry; nothing here changes.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 from pathlib import Path
 from typing import Dict, List
 
@@ -54,6 +59,19 @@ class Spec:
         module = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(module)
         return module.read
+
+    def arch(self, name: str):
+        """The module of architecture ``name``, from its own file."""
+        path = self.home / "arch" / f"{name}.py"
+        if not path.is_file():
+            raise FileNotFoundError(f"no module for arch {name!r}: looked "
+                                    f"for {path}")
+        spec = importlib.util.spec_from_file_location(
+            f"bench_arch_{name.replace('.', '_')}", path)
+        module = importlib.util.module_from_spec(spec)
+        sys.modules[spec.name] = module     # dataclasses look themselves up
+        spec.loader.exec_module(module)
+        return module
 
     def read_metrics(self, cell: str, traced: bool, record: Dict) -> Dict:
         out = {}

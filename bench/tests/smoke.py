@@ -1,5 +1,6 @@
 """A benchmark of SMOKE-width cells, laid out as the real one is, for
-rehearsals on the CPU."""
+rehearsals on the CPU; beside the real cells at SMOKE width, a cell of a
+toy architecture (``toy_mlp.py``) that the real benchmark does not have."""
 import json
 import shutil
 from pathlib import Path
@@ -16,8 +17,25 @@ SMOKE_MODEL = {"arch": "alexnet", "in_hw": 64, "channels": 3, "n_classes": 10,
 
 
 # each real cell and its traffic mix
-CELLS = {"caltech101.ckpt_preempt": "ckpt_preempt",
+CELLS = {"caltech101.ckpt_preempt_x3": "ckpt_preempt_x3",
          "caltech101.device_resize": "device_resize"}
+
+TOY_CELL = "smoke.toy"
+TOY_CONFIG = {"name": "smoke_toy",
+              "model": {"arch": "toy_mlp", "vocab": 64, "seq": 16,
+                        "hidden": 32, "lr": 0.5},
+              "batch": 8, "n_records": 192, "records_per_shard": 16,
+              "limits": {"loss_gap": 1e-5, "grad_gap": 1e-4,
+                         "delta_gap": 1e-4}}
+# saves and preemptions, far enough apart at the toy's ~1 ms steps that a
+# save drains before the next preemption abandons what is still queued
+TOY_TRAFFIC = {"about": "tests",
+               "ckpt": {"engine": "asyncbb", "every_steps": 50,
+                        "max_pending": 2},
+               "preempt": {"first_step": 30, "every_steps": 100,
+                           "deadline_s": 30}}
+# the metrics the toy cell reports: all but the image pipeline's
+TOY_LEAVES_OUT = {"decode_busy_ms", "resize_roofline"}
 
 
 def smoke_config(name: str, batch: int) -> dict:
@@ -29,12 +47,17 @@ def smoke_config(name: str, batch: int) -> dict:
 
 
 def build(root: Path) -> Spec:
-    """Write a smoke benchmark under ``root``: the real metric readers, the
-    real traffic mixes at a cadence a few seconds can hold, and a SMOKE
-    configuration of the model."""
+    """Write a smoke benchmark under ``root``: the real metric readers and
+    architectures, the real traffic mixes at a cadence a few seconds can
+    hold, a SMOKE configuration of the model, and the toy architecture's
+    module, configuration, traffic and cell."""
     real = Spec(REPO)
     home = root / "bench"
     shutil.copytree(real.home / "metrics", home / "metrics")
+    shutil.copytree(real.home / "arch", home / "arch",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(Path(__file__).with_name("toy_mlp.py"),
+                home / "arch" / "toy_mlp.py")
     (home / "traffic").mkdir(parents=True)
     (home / "configs").mkdir()
     for name in CELLS.values():
@@ -44,18 +67,25 @@ def build(root: Path) -> Spec:
         if t["preempt"]:
             t["preempt"].update(first_step=7, every_steps=9)
         (home / "traffic" / f"{name}.json").write_text(json.dumps(t))
+    (home / "traffic" / "toy_tokens.json").write_text(json.dumps(TOY_TRAFFIC))
     (home / "configs" / "smoke_caltech.json").write_text(
         json.dumps(smoke_config("smoke_caltech", 8)))
+    (home / "configs" / "smoke_toy.json").write_text(json.dumps(TOY_CONFIG))
     data = dict(real.data)
-    data["configs"] = [{"name": "smoke_caltech", "source": "tests",
-                        "reduced": [], "why": "tests",
-                        "file": "bench/configs/smoke_caltech.json"}]
+    data["configs"] = [{"name": c, "source": "tests", "reduced": [],
+                        "why": "tests", "file": f"bench/configs/{c}.json"}
+                       for c in ("smoke_caltech", "smoke_toy")]
     data["workloads"] = [
         {"name": f"smoke.{t}", "config": "smoke_caltech", "traffic": t,
          "chips": 1, "why": "tests"} for t in CELLS.values()]
+    data["workloads"].append({"name": TOY_CELL, "config": "smoke_toy",
+                              "traffic": "toy_tokens", "chips": 1,
+                              "why": "tests"})
     cells = {c: f"smoke.{t}" for c, t in CELLS.items()}
     for group in ("end_to_end", "per_layer"):
-        data[group] = [dict(m, workloads=[cells[w] for w in m["workloads"]])
-                       if "workloads" in m else m for m in data[group]]
+        data[group] = [
+            dict(m, workloads=[cells[w] for w in m["workloads"]]
+                 + ([TOY_CELL] if m["name"] not in TOY_LEAVES_OUT else []))
+            if "workloads" in m else m for m in data[group]]
     (root / "BENCHMARK.json").write_text(json.dumps(data))
     return Spec(root)

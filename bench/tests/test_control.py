@@ -15,12 +15,12 @@ def spec(tmp_path_factory):
 
 @pytest.fixture(scope="module")
 def limits(spec):
-    return spec.config(spec.cell("smoke.ckpt_preempt")["config"])["limits"]
+    return spec.config(spec.cell("smoke.ckpt_preempt_x3")["config"])["limits"]
 
 
 @pytest.fixture(scope="module")
 def readings_7(spec):
-    return readings(spec, "smoke.ckpt_preempt", 7, jax.devices()[:1])
+    return readings(spec, "smoke.ckpt_preempt_x3", 7, jax.devices()[:1])
 
 
 def fails(numbers, limits):

@@ -1,12 +1,17 @@
-"""The yardstick's counts against hand counts, and the table of peaks."""
+"""The yardstick's counts against hand counts, and the table of peaks; the
+ones a run records, through the architecture's ``counts``."""
 import pytest
 
 from bench import flops, peaks
 from bench.spec import Spec
 
-CALTECH = Spec().config("alexnet_caltech101")["model"]
+SPEC = Spec()
+CFG = SPEC.config("alexnet_caltech101")
+CALTECH = CFG["model"]
 # the same network on ImageNet-1k's 1,000 classes
 IMAGENET = dict(CALTECH, n_classes=1000)
+ALEXNET = SPEC.arch("alexnet")
+DEVICE_RESIZE = SPEC.traffic("device_resize")
 
 
 def test_alexnet_forward_macs_at_224():
@@ -27,13 +32,22 @@ def test_alexnet_params(model, n):
 def test_train_flops_leave_out_the_first_input_gradient():
     fwd = flops.alexnet_forward_macs(CALTECH)
     conv0 = flops.alexnet_layers(CALTECH)[0][1]
-    assert flops.alexnet_train_flops(CALTECH) == 2 * (3 * fwd - conv0)
+    counts = ALEXNET.counts(CFG, DEVICE_RESIZE, CFG["batch"])
+    assert counts["train_flops_per_sample"] == 2 * (3 * fwd - conv0)
 
 
 def test_resize_counts_for_a_batch_of_32():
-    assert flops.resize_bytes(32, 256, 256, 3, 224, 224) == 25_559_040
-    assert flops.resize_flops(32, 256, 256, 3, 224, 224) == \
-        96 * 2 * (224 * 256 * 256 + 224 * 256 * 224)
+    counts = ALEXNET.counts(CFG, DEVICE_RESIZE, 32)["resize"]
+    assert counts["bytes"] == 25_559_040
+    assert counts["flops"] == 96 * 2 * (224 * 256 * 256 + 224 * 256 * 224)
+
+
+@pytest.mark.parametrize("preprocess,kernel", [("pallas", True),
+                                               ("numpy", False)])
+def test_the_resize_kernel_is_counted_where_the_traffic_uses_it(preprocess,
+                                                                 kernel):
+    traffic = dict(DEVICE_RESIZE, batched_preprocess=preprocess)
+    assert ALEXNET.counts(CFG, traffic, 32)["uses_resize_kernel"] is kernel
 
 
 def test_peaks_of_a_v5e():
