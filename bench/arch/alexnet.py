@@ -78,11 +78,14 @@ def make_train_step(model: dict, devices: list):
     return alexnet.make_train_step(program_config(model))
 
 
-def first_gradient(state0: dict, state1: dict,
-                   model: dict) -> Dict[str, np.ndarray]:
-    """The first gradient as plain SGD got it: ``(p0 - p1) / lr``."""
-    p0, p1 = check.to_host(state0["params"]), check.to_host(state1["params"])
-    return {k: (p0[k] - p1[k]) / model["lr"] for k in p0}
+def first_gradient(p0: Dict[str, np.ndarray], state1: dict,
+                   model: dict) -> check.Leafwise:
+    """The first gradient as plain SGD got it, ``(p0 - p1) / lr``, from the
+    host copy of the parameters before the first step and the state after
+    it, on the device: read before the next step could donate it."""
+    lr = model["lr"]
+    return check.Leafwise(lambda a, b: (a - b) / lr, p0,
+                          check.to_host(state1["params"]))
 
 
 def counts(cfg: dict, traffic: dict, batch: int) -> dict:

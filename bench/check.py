@@ -22,11 +22,16 @@ The numbers, each compared with its limit (``value <= limit``):
 
 Leaves whose reference gradient is under a thousandth of the median leaf's
 move by round-off alone and are left out of ``grad_gap`` and ``delta_gap``.
+
+Trees come to the host in each leaf's own dtype and are widened to float64
+one leaf at a time, where a norm or a difference is taken, so the check of
+a state that fills the chip holds no whole tree in float64.
 """
 from __future__ import annotations
 
 import statistics
-from typing import Dict, List
+from collections.abc import Mapping
+from typing import Callable, Dict, List
 
 import jax
 import numpy as np
@@ -36,24 +41,49 @@ LEAF_FLOOR = 1e-3
 
 
 def to_host(tree) -> Dict[str, np.ndarray]:
-    """``{path: float64 array}`` of a parameter tree."""
+    """``{path: array}`` of a tree on the host, each leaf in its own
+    dtype."""
     flat, _ = jax.tree_util.tree_flatten_with_path(jax.device_get(tree))
-    return {jax.tree_util.keystr(p): np.asarray(v, np.float64)
-            for p, v in flat}
+    return {jax.tree_util.keystr(p): np.asarray(v) for p, v in flat}
 
 
-def leaf_gaps(prog: Dict[str, np.ndarray], ref: Dict[str, np.ndarray],
-              keep: List[str]) -> Dict[str, float]:
+class Leafwise(Mapping):
+    """``{path: fn(*leaves)}`` over trees of the same paths, each leaf
+    widened to float64 and ``fn`` applied only when the leaf is read."""
+
+    def __init__(self, fn: Callable, *trees: Dict[str, np.ndarray]):
+        self.fn, self.trees = fn, trees
+
+    def __getitem__(self, k: str) -> np.ndarray:
+        return self.fn(*(np.asarray(t[k], np.float64) for t in self.trees))
+
+    def __iter__(self):
+        return iter(self.trees[0])
+
+    def __len__(self) -> int:
+        return len(self.trees[0])
+
+
+def change(p0, pn) -> Leafwise:
+    """``pn - p0``, leaf by leaf in float64."""
+    return Leafwise(np.subtract, pn, p0)
+
+
+def norm(x) -> float:
+    return float(np.linalg.norm(np.asarray(x, np.float64)))
+
+
+def leaf_gaps(prog: Mapping, ref: Mapping, keep: List[str]) -> Dict[str, float]:
     """Each kept leaf's gap of norms, against the larger of its reference
     norm and the median leaf's."""
-    norms = {k: float(np.linalg.norm(ref[k])) for k in keep}
+    norms = {k: norm(ref[k]) for k in keep}
     med = statistics.median(norms.values())
-    return {k: abs(float(np.linalg.norm(prog[k])) - norms[k])
-            / max(norms[k], med) for k in keep}
+    return {k: abs(norm(prog[k]) - norms[k]) / max(norms[k], med)
+            for k in keep}
 
 
-def kept_leaves(g_ref: Dict[str, np.ndarray]) -> List[str]:
-    norms = {k: float(np.linalg.norm(v)) for k, v in g_ref.items()}
+def kept_leaves(g_ref: Mapping) -> List[str]:
+    norms = {k: norm(v) for k, v in g_ref.items()}
     med = statistics.median(norms.values())
     return [k for k, v in norms.items() if v >= LEAF_FLOOR * med]
 
@@ -67,13 +97,12 @@ def training_gaps(prog, ref) -> Dict[str, float]:
     losses, p0, g1, pn = prog
     ref_losses, ref_p0, ref_g1, ref_pn = ref
     keep = kept_leaves(ref_g1)
-    delta = {k: pn[k] - p0[k] for k in keep}
-    ref_delta = {k: ref_pn[k] - ref_p0[k] for k in keep}
     return {
         "loss_gap": max(abs(a - b) / abs(b) for a, b in zip(losses,
                                                             ref_losses)),
         "grad_gap": max(leaf_gaps(g1, ref_g1, keep).values()),
-        "delta_gap": max(leaf_gaps(delta, ref_delta, keep).values()),
+        "delta_gap": max(leaf_gaps(change(p0, pn), change(ref_p0, ref_pn),
+                                   keep).values()),
     }
 
 

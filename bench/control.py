@@ -56,8 +56,8 @@ def _readings(spec, cell_name, seed, devices, workdir, make_train_step):
 
     keep = check.kept_leaves(rg1)
     grad = check.leaf_gaps(g1, rg1, keep)
-    delta = check.leaf_gaps({k: pn[k] - p0[k] for k in keep},
-                            {k: rpn[k] - rp0[k] for k in keep}, keep)
+    delta = check.leaf_gaps(check.change(p0, pn), check.change(rp0, rpn),
+                            keep)
     program["grad_leaf"] = max(grad, key=grad.get)
     program["delta_leaf"] = max(delta, key=delta.get)
     program["leaves_left_out"] = sorted(set(rg1) - set(keep))

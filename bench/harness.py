@@ -134,21 +134,19 @@ def record(run: CellRun, *, setup_s: float, peaks, tracer=None,
 
 
 def judge(run: CellRun, keep_reference: bool = False):
-    """The numbers ``correct`` compares.  The program's arrays come to the
-    host and its device state is freed before the reference runs.  With
-    ``keep_reference``, also returns the program's and the reference's
+    """The numbers ``correct`` compares.  What set-up kept of the first
+    steps is on the host already; the kept batches and the saved states
+    come to the host and are freed on the device before the reference runs.
+    With ``keep_reference``, also returns the program's and the reference's
     trajectories, the reference's batches and the program's, for the
     readings of the control and the faults."""
     arch, model = run.arch, run.model
     host_batches = [jax.device_get(b) for b in run.first_batches]
-    states = run.states_seen
-    prog = (list(run.first_losses), check.to_host(states[0]["params"]),
-            arch.first_gradient(states[0], states[1], model),
-            check.to_host(states[-1]["params"]))
+    prog = (list(run.first_losses), *run.kept)
     resumes = [(check.state_mismatches(r.saved_state, r.restored.state),
                 check.batches_differ(r.next_batch, r.first_batch))
                for r in run.resumes]
-    run.first_batches = run.states_seen = run.resumes = states = None
+    run.first_batches = run.kept = run.resumes = None
     gc.collect()
     numbers, ref_batches = arch.check_batches(run.corpus, host_batches, model)
     ref = arch.reference_steps(run.seed, model, ref_batches, run.devices[0])

@@ -10,6 +10,7 @@ new file.
 """
 from __future__ import annotations
 
+import math
 import os
 import time
 from dataclasses import dataclass
@@ -20,16 +21,10 @@ import jax
 from repro.core import CheckpointManager, ResumableIterator, make_storage
 from repro.train.trainer import Trainer
 
-from . import hooks
+from . import check, hooks
 
 FIRST_STEPS = 3        # set-up steps, the ones the reference follows
-CHUNK = 25             # steps per Trainer.run call between deadline checks
-
-
-def _kept(state: dict) -> dict:
-    """What the check needs of a state: all of it but the step counter,
-    which would stay on the device beside it for nothing."""
-    return {k: v for k, v in state.items() if k != "step"}
+CHUNK = 25             # most steps per Trainer.run call between deadline checks
 
 
 @dataclass
@@ -132,8 +127,11 @@ class CellRun:
     def setup(self) -> None:
         """Corpus, state, pipeline, trainer; then the first steps through
         the window's own call and feed, which compile every program the
-        window runs.  Keeps what the reference needs of them: the states
-        before the first step, after it and after the last."""
+        window runs.  Keeps what the reference needs of them, copied to the
+        host as it is taken and before the next step could donate it
+        (``kept``): the parameters before the first step, the first
+        gradient as the optimizer got it, and the parameters after the
+        last.  The only state left on the device is the trainer's."""
         t0 = time.monotonic()
         self.corpus = self.arch.build_corpus(
             self.cfg, self.seed, os.path.join(self.workdir, "corpus"))
@@ -144,24 +142,30 @@ class CellRun:
         self.skeleton = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state)
         self.seg = self._segment(state, resume=False, keep=FIRST_STEPS)
-        self.states_seen = [_kept(state)]
-        self.seg.trainer.run(1)
-        self.states_seen.append(_kept(self.seg.trainer.state))
-        self.seg.trainer.run(FIRST_STEPS - 1)
-        self.states_seen.append(_kept(self.seg.trainer.state))
-        self.first_losses = [h["loss"] for h in self.seg.trainer.history]
+        p0 = check.to_host(state["params"])
+        del state
+        trainer = self.seg.trainer
+        trainer.run(1)
+        g1 = self.arch.first_gradient(p0, trainer.state, self.model)
+        trainer.run(FIRST_STEPS - 1)
+        self.kept = (p0, g1, check.to_host(trainer.state["params"]))
+        self.first_losses = [h["loss"] for h in trainer.history]
         self.first_batches = list(self.seg.feed.kept)
-        jax.block_until_ready(self.seg.trainer.state)
         self.setup_phases = {"corpus_s": t1 - t0,
                              "weights_and_first_steps_s":
                              time.monotonic() - t1}
 
     # -- the window ----------------------------------------------------------
     def window(self, seconds: float) -> None:
+        """Train until the close.  Each call makes at most the steps that
+        the step time of the call before says fit in the time left (the
+        first call one step, to time it), so the window's last step begins
+        before the close and ends within about one step of it."""
         self.t_w0 = time.monotonic()
         self.t_w1 = self.t_w0 + seconds
         pre = self.traffic.get("preempt") or {}
-        while time.monotonic() < self.t_w1:
+        step_s = None
+        while (now := time.monotonic()) < self.t_w1:
             if self.next_preempt is not None \
                     and self.cur + 1 >= self.next_preempt:
                 self._preempt_and_resume()
@@ -169,10 +173,14 @@ class CellRun:
                 if len(self.preempt_steps) == pre.get("count"):
                     self.next_preempt = None
                 continue
-            n = CHUNK
+            n = 1 if step_s is None else min(
+                CHUNK, max(1, math.ceil((self.t_w1 - now) / step_s)))
             if self.next_preempt is not None:
                 n = min(n, self.next_preempt - self.cur - 1)
+            done = len(self.steps)
             self.seg.trainer.run(n)
+            if len(self.steps) > done:
+                step_s = (time.monotonic() - now) / (len(self.steps) - done)
 
     def _preempt_and_resume(self) -> None:
         """The next step is the preempted one: it runs, saves and stops

@@ -1,6 +1,8 @@
 """A benchmark of SMOKE-width cells, laid out as the real one is, for
-rehearsals on the CPU; beside the real cells at SMOKE width, a cell of a
-toy architecture (``toy_mlp.py``) that the real benchmark does not have."""
+rehearsals on the CPU; beside the real cells at SMOKE width, cells of two
+toy architectures that the real benchmark does not have: a token MLP under
+SGD (``toy_mlp.py``) and the same MLP under Adam in a step that donates its
+state (``toy_adam.py``)."""
 import json
 import shutil
 from pathlib import Path
@@ -27,6 +29,15 @@ TOY_CONFIG = {"name": "smoke_toy",
               "batch": 8, "n_records": 192, "records_per_shard": 16,
               "limits": {"loss_gap": 1e-5, "grad_gap": 1e-4,
                          "delta_gap": 1e-4}}
+TOY_ADAM_CELL = "smoke.toy_adam"
+TOY_ADAM_CONFIG = {"name": "smoke_toy_adam",
+                   "model": {"arch": "toy_adam", "vocab": 64, "seq": 16,
+                             "hidden": 32, "lr": 0.01, "b1": 0.9,
+                             "b2": 0.999, "eps": 1e-8},
+                   "batch": 8, "n_records": 192, "records_per_shard": 16,
+                   "limits": {"loss_gap": 1e-5, "grad_gap": 1e-4,
+                              "delta_gap": 1e-4}}
+TOYS = {TOY_CELL: TOY_CONFIG, TOY_ADAM_CELL: TOY_ADAM_CONFIG}
 # saves and preemptions, far enough apart at the toy's ~1 ms steps that a
 # save drains before the next preemption abandons what is still queued
 TOY_TRAFFIC = {"about": "tests",
@@ -49,15 +60,15 @@ def smoke_config(name: str, batch: int) -> dict:
 def build(root: Path) -> Spec:
     """Write a smoke benchmark under ``root``: the real metric readers and
     architectures, the real traffic mixes at a cadence a few seconds can
-    hold, a SMOKE configuration of the model, and the toy architecture's
-    module, configuration, traffic and cell."""
+    hold, a SMOKE configuration of the model, and the toy architectures'
+    modules, configurations, traffic and cells."""
     real = Spec(REPO)
     home = root / "bench"
     shutil.copytree(real.home / "metrics", home / "metrics")
     shutil.copytree(real.home / "arch", home / "arch",
                     ignore=shutil.ignore_patterns("__pycache__"))
-    shutil.copy(Path(__file__).with_name("toy_mlp.py"),
-                home / "arch" / "toy_mlp.py")
+    for toy in ("toy_mlp.py", "toy_adam.py"):
+        shutil.copy(Path(__file__).with_name(toy), home / "arch" / toy)
     (home / "traffic").mkdir(parents=True)
     (home / "configs").mkdir()
     for name in CELLS.values():
@@ -70,22 +81,24 @@ def build(root: Path) -> Spec:
     (home / "traffic" / "toy_tokens.json").write_text(json.dumps(TOY_TRAFFIC))
     (home / "configs" / "smoke_caltech.json").write_text(
         json.dumps(smoke_config("smoke_caltech", 8)))
-    (home / "configs" / "smoke_toy.json").write_text(json.dumps(TOY_CONFIG))
+    for cfg in TOYS.values():
+        (home / "configs" / f"{cfg['name']}.json").write_text(json.dumps(cfg))
     data = dict(real.data)
     data["configs"] = [{"name": c, "source": "tests", "reduced": [],
                         "why": "tests", "file": f"bench/configs/{c}.json"}
-                       for c in ("smoke_caltech", "smoke_toy")]
+                       for c in ["smoke_caltech"]
+                       + [cfg["name"] for cfg in TOYS.values()]]
     data["workloads"] = [
         {"name": f"smoke.{t}", "config": "smoke_caltech", "traffic": t,
          "chips": 1, "why": "tests"} for t in CELLS.values()]
-    data["workloads"].append({"name": TOY_CELL, "config": "smoke_toy",
-                              "traffic": "toy_tokens", "chips": 1,
-                              "why": "tests"})
+    data["workloads"] += [{"name": cell, "config": cfg["name"],
+                           "traffic": "toy_tokens", "chips": 1, "why": "tests"}
+                          for cell, cfg in TOYS.items()]
     cells = {c: f"smoke.{t}" for c, t in CELLS.items()}
     for group in ("end_to_end", "per_layer"):
         data[group] = [
             dict(m, workloads=[cells[w] for w in m["workloads"]]
-                 + ([TOY_CELL] if m["name"] not in TOY_LEAVES_OUT else []))
+                 + (list(TOYS) if m["name"] not in TOY_LEAVES_OUT else []))
             if "workloads" in m else m for m in data[group]]
     (root / "BENCHMARK.json").write_text(json.dumps(data))
     return Spec(root)

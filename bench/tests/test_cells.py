@@ -10,6 +10,7 @@ import sys
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from bench import flops, harness
@@ -41,6 +42,8 @@ def run(spec, cell, trace=0, seconds=2, **kw):
     ("smoke.device_resize", {"samples_per_s", "step_ms_p95", "setup_s"}),
     (smoke.TOY_CELL, {"samples_per_s", "step_ms_p95", "resume_s",
                       "ckpt_commit_s", "setup_s"}),
+    (smoke.TOY_ADAM_CELL, {"samples_per_s", "step_ms_p95", "resume_s",
+                           "ckpt_commit_s", "setup_s"}),
 ])
 def test_untraced_run(spec, cell, metrics):
     result, err = run(spec, cell)
@@ -64,6 +67,8 @@ LAYER_METRICS = {"data_wait_ms", "decode_busy_ms", "resize_roofline",
                                                 "restore_ms"}),
     ("smoke.device_resize", LAYER_METRICS),
     (smoke.TOY_CELL, LAYER_METRICS - smoke.TOY_LEAVES_OUT
+     | {"ckpt_blocked_ms", "ckpt_drain_ms", "restore_ms"}),
+    (smoke.TOY_ADAM_CELL, LAYER_METRICS - smoke.TOY_LEAVES_OUT
      | {"ckpt_blocked_ms", "ckpt_drain_ms", "restore_ms"}),
 ])
 def test_traced_run(spec, monkeypatch, cell, metrics):
@@ -198,15 +203,17 @@ def test_an_unknown_arch_fails_in_set_up(tmp_path):
 
 
 # -- the timed path broken underneath: correct must come out false ----------
-# each wraps the architecture's own step, so serves every architecture
+# each wraps the architecture's own step, so serves every architecture, also
+# one that donates its state
 
 def unchanged_step(arch):
     def make(model, devices):
         inner = arch.make_train_step(model, devices)
 
         def step(state, batch):
+            params = jax.tree.map(jnp.copy, state["params"])
             new, metrics = inner(state, batch)
-            return dict(new, params=state["params"]), metrics
+            return dict(new, params=params), metrics
         return step
     return make
 
@@ -226,6 +233,9 @@ def half_batch_step(arch):
     ("smoke.ckpt_preempt_x3", unchanged_step, "grad_gap"),
     ("smoke.ckpt_preempt_x3", half_batch_step, "loss_gap"),
     (smoke.TOY_CELL, unchanged_step, "grad_gap"),
+    # Adam's moments still move: the unchanged parameters show in the change
+    (smoke.TOY_ADAM_CELL, unchanged_step, "delta_gap"),
+    (smoke.TOY_ADAM_CELL, half_batch_step, "loss_gap"),
 ])
 def test_a_broken_step_is_not_correct(spec, cell, make, fails):
     arch = spec.arch(spec.config(spec.cell(cell)["config"])["model"]["arch"])

@@ -68,24 +68,25 @@ def _init(bits, vocab: int, hidden: int) -> dict:
             "bo": jnp.zeros((vocab,), jnp.float32)}
 
 
-def _params(seed: int, model: dict) -> dict:
+def make_params(seed: int, model: dict) -> dict:
     return jax.jit(_init, static_argnums=(1, 2))(
         jnp.asarray(key_data(seed, 1)), model["vocab"], model["hidden"])
 
 
 def make_state(seed: int, model: dict, devices: list) -> dict:
     with jax.default_device(devices[0]):
-        return {"params": _params(seed, model), "step": jnp.int32(0)}
+        return {"params": make_params(seed, model), "step": jnp.int32(0)}
+
+
+def loss_fn(p, tokens):
+    x, y = tokens[:, :-1], tokens[:, 1:]
+    h = jnp.tanh(p["embed"][x] @ p["w1"] + p["b1"])
+    logp = jax.nn.log_softmax(h @ p["out"] + p["bo"])
+    return -jnp.mean(jnp.take_along_axis(logp, y[..., None], -1))
 
 
 def make_train_step(model: dict, devices: list):
     lr = model["lr"]
-
-    def loss_fn(p, tokens):
-        x, y = tokens[:, :-1], tokens[:, 1:]
-        h = jnp.tanh(p["embed"][x] @ p["w1"] + p["b1"])
-        logp = jax.nn.log_softmax(h @ p["out"] + p["bo"])
-        return -jnp.mean(jnp.take_along_axis(logp, y[..., None], -1))
 
     @jax.jit
     def train_step(state, tokens):
@@ -96,9 +97,10 @@ def make_train_step(model: dict, devices: list):
     return train_step
 
 
-def first_gradient(state0, state1, model):
-    p0, p1 = check.to_host(state0["params"]), check.to_host(state1["params"])
-    return {k: (p0[k] - p1[k]) / model["lr"] for k in p0}
+def first_gradient(p0, state1, model):
+    lr = model["lr"]
+    return check.Leafwise(lambda a, b: (a - b) / lr, p0,
+                          check.to_host(state1["params"]))
 
 
 def counts(cfg: dict, traffic: dict, batch: int) -> dict:
@@ -124,7 +126,7 @@ def check_batches(corpus: Corpus, batches, model: dict):
     return {"rows_wrong": wrong}, ref
 
 
-def _ref_loss(p, tokens, vocab: int, dtype):
+def ref_loss(p, tokens, vocab: int, dtype):
     x = jax.nn.one_hot(tokens[:, :-1], vocab, dtype=jnp.float32)
     y = jax.nn.one_hot(tokens[:, 1:], vocab, dtype=jnp.float32)
 
@@ -142,9 +144,9 @@ def reference_steps(seed: int, model: dict, ref, device, *, lower=False,
                     rows=None):
     """``lower``: bfloat16 operands, one step below float32."""
     dtype = jnp.bfloat16 if lower else jnp.float32
-    grad = jax.jit(jax.value_and_grad(_ref_loss), static_argnums=(2, 3))
+    grad = jax.jit(jax.value_and_grad(ref_loss), static_argnums=(2, 3))
     with jax.default_device(device), jax.default_matmul_precision("highest"):
-        params = _params(seed, model)
+        params = make_params(seed, model)
         p0, losses, g1 = check.to_host(params), [], None
         for k, tokens in enumerate(ref):
             loss, g = grad(params, jnp.asarray(tokens[:rows]), model["vocab"],
