@@ -52,7 +52,8 @@ import threading
 from collections import deque
 from concurrent.futures import FIRST_COMPLETED, Future
 from concurrent.futures import wait as futures_wait
-from typing import Any, Callable, Iterable, Iterator, List, Optional, Sequence
+from typing import (Any, Callable, Iterable, Iterator, List, NamedTuple,
+                    Optional, Sequence)
 
 import numpy as np
 
@@ -123,13 +124,36 @@ def _take_future(window: List[Future], deterministic: bool) -> Future:
 
 
 class _InterleaveSlot:
-    """One cycle slot: an input element and its lazily-opened sub-iterator."""
+    """One cycle slot: an input element and its lazily-opened sub-iterator.
 
-    __slots__ = ("item", "it")
+    A slot placed from an :class:`InterleaveState` opens its sub-stream
+    ``skip`` elements in, and its first turn delivers ``block`` elements
+    (``None``: a full block) and, if ``last``, retires the slot after them;
+    a slot with nothing left to deliver retires unopened."""
 
-    def __init__(self, item: Any):
+    __slots__ = ("item", "it", "skip", "block", "last")
+
+    def __init__(self, item: Any, skip: int = 0,
+                 block: Optional[int] = None, last: bool = False):
         self.item = item
         self.it: Optional[Iterator] = None
+        self.skip = skip
+        self.block = block
+        self.last = last
+
+
+class InterleaveState(NamedTuple):
+    """Where :meth:`Dataset.interleave` stands after some delivered
+    elements, as :func:`interleave_state` derives it with no I/O.
+
+    ``cycle``: the cycle's slots in round-robin order, head first, as
+    ``(source_index, taken, block, last)``: elements taken, the elements
+    its next turn delivers, and whether the slot retires after that turn;
+    ``entered``: source elements that have entered the cycle (retired ones
+    included)."""
+
+    cycle: tuple
+    entered: int
 
 
 def _shard_key(item: Any) -> Any:
@@ -188,10 +212,31 @@ class ShardQuarantine:
 
 
 class Dataset:
-    """Lazily-evaluated pipeline node; iterate to pull elements through."""
+    """Lazily-evaluated pipeline node; iterate to pull elements through.
 
-    def __init__(self, gen_fn: Callable[[], Iterator]):
+    ``seek``, optional, is ``start -> Dataset``: the same stream from
+    element ``start`` on, positioned without producing the elements before
+    it.  Transformations do not carry it forward; only a builder that can
+    prove its stream's order attaches it (:func:`sharded_image_pipeline`).
+    """
+
+    def __init__(self, gen_fn: Callable[[], Iterator],
+                 seek: Optional[Callable[[int], "Dataset"]] = None):
         self._gen_fn = gen_fn
+        self._seek = seek
+
+    @property
+    def seekable(self) -> bool:
+        """True where :meth:`seek` positions the stream without reading."""
+        return self._seek is not None
+
+    def seek(self, start: int) -> Optional["Dataset"]:
+        """This stream from element ``start`` on, or ``None`` where the
+        Dataset cannot position itself without reading the elements
+        before it.  A ``start`` at or past the end gives an empty stream."""
+        if self._seek is None:
+            return None
+        return self._seek(max(int(start), 0))
 
     # -- sources ---------------------------------------------------------------
     @staticmethod
@@ -320,10 +365,18 @@ class Dataset:
         block_length: int = 1,
         num_parallel_calls: int = 0,
         quarantine: Optional[ShardQuarantine] = None,
+        start: Optional[InterleaveState] = None,
     ) -> "Dataset":
         """Expand each input element to a sub-stream via ``fn`` and interleave
         ``cycle_length`` of them round-robin, ``block_length`` elements at a
         time (tf.data ``parallel_interleave``).
+
+        ``start`` (from :func:`interleave_state`) begins the stream where
+        that state stands: the first ``start.entered`` upstream elements
+        are pulled without opening any but the cycle's, whose sub-streams
+        open advanced past the elements already taken.  The result is the
+        unstarted stream from that element on, provided every sub-stream
+        holds the counts the state was derived from.
 
         With ``num_parallel_calls > 1`` the next block of up to
         ``min(cycle_length, num_parallel_calls)`` slots is fetched on the
@@ -348,6 +401,8 @@ class Dataset:
             raise ValueError(f"cycle_length must be >= 1, got {cycle_length}")
         if block_length < 1:
             raise ValueError(f"block_length must be >= 1, got {block_length}")
+        if start is not None and quarantine is not None:
+            raise ValueError("start= cannot model a quarantine's skips")
         upstream = self._gen_fn
         fn_label = getattr(fn, "__name__", "interleave_fn")
 
@@ -359,9 +414,19 @@ class Dataset:
             with trace.span(trace.STAGE_DECODE, fn_label), \
                     metrics.timer("pipeline.interleave_block_s"):
                 out: List[Any] = []
+                if slot.block is None:
+                    n, last = block_length, False
+                else:
+                    n, last = slot.block, slot.last
+                    slot.block = None
+                    if n == 0:
+                        return out, last    # spent: retires unopened
                 if slot.it is None:
                     try:
                         slot.it = iter(fn(slot.item))
+                        if slot.skip:
+                            next(itertools.islice(slot.it, slot.skip,
+                                                  slot.skip), None)
                     except Exception as e:
                         # a shard we could not even open is dropped from the
                         # cycle — with a RetryingStorage underneath, the
@@ -371,7 +436,7 @@ class Dataset:
                         if quarantine is not None:
                             quarantine.quarantine(slot.item, e)
                         return [_ErrorMarker(e)], True
-                for _ in range(block_length):
+                for _ in range(n):
                     try:
                         out.append(next(slot.it))
                     except StopIteration:
@@ -382,7 +447,7 @@ class Dataset:
                             quarantine.quarantine(slot.item, e)
                         out.append(_ErrorMarker(e))
                         return out, True
-                return out, False
+                return out, last
 
         def _probe_readmit(item) -> bool:
             """One cheap open + single-record pull of a quarantined shard.
@@ -400,6 +465,17 @@ class Dataset:
         parallel = num_parallel_calls > 1
         window = min(cycle_length, num_parallel_calls) if parallel else 0
 
+        def _placed(src) -> deque:
+            """The cycle ``start`` describes, its upstream elements pulled
+            from ``src``; only the cycle's are kept."""
+            wanted = {slot[0] for slot in start.cycle}
+            items = {i: item for i, item in
+                     enumerate(itertools.islice(src, start.entered))
+                     if i in wanted}
+            return deque(_InterleaveSlot(items[i], taken, block, last)
+                         for i, taken, block, last in start.cycle
+                         if i in items)
+
         def gen():
             pool = reader_pool(num_parallel_calls) if parallel else None
             src = upstream()
@@ -407,6 +483,8 @@ class Dataset:
             futs: dict = {}             # slot -> in-flight block fetch
             src_done = False
             try:
+                if start is not None:
+                    cycle = _placed(src)
                 while True:
                     while len(cycle) < cycle_length and not src_done:
                         try:
@@ -753,6 +831,59 @@ def _accepts_start(factory: Callable) -> bool:
                for p in params)
 
 
+class _InterleaveModel:
+    """:meth:`Dataset.interleave` run on per-source element ``counts``
+    (sources in upstream order) with no I/O, one cycle turn at a time: the
+    one model that :func:`interleave_order` and :func:`interleave_state`
+    read.
+
+    Faithful to one subtlety of the real operator: exhaustion is only
+    observed on ``StopIteration``, so a source whose remaining count is an
+    exact ``block_length`` multiple is re-appended after its last full
+    block and occupies one extra (empty) cycle turn before retiring.
+    """
+
+    def __init__(self, counts: Sequence[int], cycle_length: int,
+                 block_length: int):
+        if cycle_length < 1:
+            raise ValueError(f"cycle_length must be >= 1, got {cycle_length}")
+        if block_length < 1:
+            raise ValueError(f"block_length must be >= 1, got {block_length}")
+        self.counts = [int(c) for c in counts]
+        self.cycle_length = cycle_length
+        self.block_length = block_length
+        self.pos = [0] * len(self.counts)   # elements taken, per source
+        self.cycle: deque = deque()         # the slots behind the turn's
+        self.entered = 0
+
+    def next_turn(self, s: int) -> tuple:
+        """Source ``s``'s next turn as an :class:`InterleaveState` slot."""
+        take = min(self.block_length, self.counts[s] - self.pos[s])
+        return s, self.pos[s], take, take < self.block_length
+
+    def turns(self) -> Iterator[tuple]:
+        """Yield ``(source, first, take)`` per turn: the source delivers
+        elements ``first .. first + take - 1``.  While a turn is yielded,
+        ``cycle``, ``pos`` and ``entered`` describe the operator with that
+        source popped and its block not yet taken."""
+        pos, cycle = self.pos, self.cycle
+        while True:
+            while len(cycle) < self.cycle_length \
+                    and self.entered < len(self.counts):
+                cycle.append(self.entered)
+                self.entered += 1
+            if not cycle:
+                return
+            s, first, take, _ = self.next_turn(cycle.popleft())
+            yield s, first, take
+            pos[s] += take
+            if take == self.block_length:
+                # a full block: StopIteration not yet observed — the slot
+                # stays in the cycle even if it is now empty (one extra
+                # empty turn)
+                cycle.append(s)
+
+
 def interleave_order(counts: Sequence[int], cycle_length: int = 4,
                      block_length: int = 1) -> List[tuple]:
     """Arithmetic replica of :meth:`Dataset.interleave` delivery order.
@@ -763,37 +894,30 @@ def interleave_order(counts: Sequence[int], cycle_length: int = 4,
     every sub-stream is error-free.  Zero I/O: this is how a seekable
     pipeline (:func:`sharded_record_dataset`) converts a flat resume
     offset into per-shard read positions.
-
-    Faithful to one subtlety of the real operator: exhaustion is only
-    observed on ``StopIteration``, so a source whose remaining count is an
-    exact ``block_length`` multiple is re-appended after its last full
-    block and occupies one extra (empty) cycle turn before retiring.
     """
-    if cycle_length < 1:
-        raise ValueError(f"cycle_length must be >= 1, got {cycle_length}")
-    if block_length < 1:
-        raise ValueError(f"block_length must be >= 1, got {block_length}")
-    order: List[tuple] = []
-    remaining = [int(c) for c in counts]
-    pos = [0] * len(remaining)
-    cycle: deque = deque()
-    nxt = 0
-    while True:
-        while len(cycle) < cycle_length and nxt < len(remaining):
-            cycle.append(nxt)
-            nxt += 1
-        if not cycle:
-            return order
-        s = cycle.popleft()
-        take = min(block_length, remaining[s])
-        for _ in range(take):
-            order.append((s, pos[s]))
-            pos[s] += 1
-        remaining[s] -= take
-        if take == block_length:
-            # a full block: StopIteration not yet observed — the slot stays
-            # in the cycle even if it is now empty (one extra empty turn)
-            cycle.append(s)
+    model = _InterleaveModel(counts, cycle_length, block_length)
+    return [(s, i) for s, first, take in model.turns()
+            for i in range(first, first + take)]
+
+
+def interleave_state(counts: Sequence[int], cycle_length: int,
+                     block_length: int, offset: int) -> InterleaveState:
+    """Where :meth:`Dataset.interleave` over sources of ``counts``
+    elements stands once ``offset`` elements are delivered, with no I/O
+    (the model of :func:`interleave_order`).  ``interleave(...,
+    start=state)`` continues from there.  At or past the end: an empty
+    cycle with every source entered."""
+    model = _InterleaveModel(counts, cycle_length, block_length)
+    delivered = 0
+    for s, first, take in model.turns():
+        if offset < delivered + take:
+            cut = offset - delivered
+            head = (s, first + cut, take - cut, take < block_length)
+            return InterleaveState(
+                (head,) + tuple(model.next_turn(c) for c in model.cycle),
+                model.entered)
+        delivered += take
+    return InterleaveState((), model.entered)
 
 
 def sharded_record_dataset(storage, paths: Sequence[str], rec_bytes: int, *,
@@ -867,8 +991,10 @@ class ResumableIterator:
     "offset": k}`` — *k elements of epoch e already delivered to the
     consumer*.  :meth:`state` is cheap enough to attach to every checkpoint
     (the trainer stores it in ``extra_meta["pipeline"]``);
-    :meth:`restore_state` re-opens epoch ``e`` and deterministically skips
-    ``k`` elements, so a resumed run neither skips nor replays samples.
+    :meth:`restore_state` re-opens epoch ``e`` past its first ``k``
+    elements, by a seek where the pipeline offers one and else by a
+    deterministic replay, so a resumed run neither skips nor replays
+    samples.
 
     ``source`` is either a :class:`Dataset` (re-iterated per epoch — same
     element order every epoch) or a factory ``epoch -> Dataset`` for
@@ -879,17 +1005,24 @@ class ResumableIterator:
     of ``prefetch`` (wrap the whole pipeline) so buffered-but-unconsumed
     elements are not counted as seen.
 
-    **O(1) seek**: a factory that also accepts a start offset —
-    ``(epoch, start) -> Dataset`` yielding epoch ``e``'s stream *from
-    element* ``start`` (e.g. built on :func:`sharded_record_dataset`,
-    which positions arithmetically instead of reading) — upgrades
-    :meth:`restore_state` from O(offset) replay to a direct seek: the
-    factory is opened at the checkpointed offset and no skipped element
-    is ever produced, so resume cost is independent of how deep into the
-    epoch the checkpoint was.  Seekability is detected from the factory's
-    signature; :meth:`state` then carries ``"seek": True`` so a restore
-    on a non-seekable pipeline of the same corpus still works (it falls
-    back to replay).
+    **Seek instead of replay**, two ways, tried in order:
+
+    1. a factory that also accepts a start offset — ``(epoch, start) ->
+       Dataset`` yielding epoch ``e``'s stream *from element* ``start``
+       (e.g. built on :func:`sharded_record_dataset`, which positions
+       arithmetically instead of reading).  Detected from the factory's
+       signature; :meth:`state` then carries ``"seek": True`` so a restore
+       on a non-seekable pipeline of the same corpus still works (it
+       falls back to replay);
+    2. a Dataset that can position itself (:attr:`Dataset.seekable`,
+       e.g. :func:`sharded_image_pipeline` with ``labels_per_shard``):
+       the epoch's Dataset is opened through :meth:`Dataset.seek`.
+
+    Either way no skipped element is ever produced, so resume cost is
+    independent of how deep into the epoch the checkpoint was; counted in
+    ``pipeline.resume_seeks``, the second traced as ``resume_seek``.  A
+    replay is counted in ``pipeline.resume_skipped`` and traced as
+    ``resume_skip``.
 
     Determinism caveat: skip-restore replays the pipeline's element order,
     which is deterministic for ``deterministic=True`` stages (the default);
@@ -930,7 +1063,8 @@ class ResumableIterator:
 
     def restore_state(self, state: dict) -> None:
         """Re-open at ``state``: a direct seek when the factory supports a
-        start offset, else by skipping already-delivered elements."""
+        start offset or the epoch's Dataset is seekable, else by skipping
+        already-delivered elements."""
         self.close()
         self._epoch = int(state["epoch"])
         self._offset = 0
@@ -945,7 +1079,17 @@ class ResumableIterator:
             self._offset = target
             metrics.inc("pipeline.resume_seeks")
             return
-        self._it = self._open_epoch(self._epoch)
+        ds = self._factory(self._epoch)
+        if target > 0 and getattr(ds, "seekable", False):
+            # the Dataset positions itself arithmetically; an empty tail
+            # past the epoch end rolls the epoch, as above
+            with trace.span(trace.STAGE_DATA_WAIT,
+                            f"resume_seek:{target}@epoch{self._epoch}"):
+                self._it = iter(ds.seek(target))
+            self._offset = target
+            metrics.inc("pipeline.resume_seeks")
+            return
+        self._it = iter(ds)
         with trace.span(trace.STAGE_DATA_WAIT,
                         f"resume_skip:{target}@epoch{self._epoch}"):
             for _ in range(target):
@@ -1141,6 +1285,23 @@ def sharded_image_pipeline(
     ReadaheadScheduler`, or ``True``/an int window to build one over the
     cache (requires ``cache``).  ``quarantine`` enables cross-epoch shard
     quarantine with probe-read re-admission (see :class:`ShardQuarantine`).
+
+    **Seek.**  With ``labels_per_shard`` (each shard's record count is its
+    labels' length), and with no ``quarantine``, ``readahead`` or
+    ``repeat``, the returned Dataset is seekable: ``ds.seek(k)`` is the
+    stream from batch ``k`` on, which :class:`ResumableIterator` uses on a
+    restore.  Batch ``k`` begins at record ``k * batch_size``; the shard
+    order is replayed in pure Python and the interleave's state at that
+    record derived by :func:`interleave_state`, so only the shards still
+    in the cycle or after it are read, and everything after the cut runs
+    the same decode, batch and prefetch as a fresh epoch.  No seek is
+    offered, and a restore replays, without the counts, with a quarantine
+    (whose skips the model cannot know), with readahead (which would
+    announce, and so read, the shards before the cut) or with ``repeat``.
+    Caveat (as for :func:`sharded_record_dataset` and tf.data): the
+    pipeline ignores errors, and a record before the cut that fails to
+    read or decode shifts the count, so the seek starts one record early
+    for each.
     """
     from . import records
 
@@ -1216,61 +1377,84 @@ def sharded_image_pipeline(
             blob = storage.read_file(path)
             return records.iter_record_views(blob)
 
-    ds = src.interleave(
-        stream_shard, cycle_length=cycle_length, block_length=block_length,
-        num_parallel_calls=num_parallel_calls, quarantine=quarantine)
+    def decode_and_batch(ds: Dataset) -> Dataset:
+        """Everything after the interleave: decode, batch, resize,
+        prefetch."""
+        if not preprocess:
+            # read-only mode (fig5): element = record byte length
+            def record_len(item):
+                view = item[0] if labels_per_shard is not None else item
+                return np.int64(len(view))
 
-    if not preprocess:
-        # read-only mode (fig5): element = record byte length
-        def record_len(item):
-            view = item[0] if labels_per_shard is not None else item
-            return np.int64(len(view))
+            ds = ds.map(record_len).ignore_errors()
+            ds = ds.batch(batch_size, drop_remainder=True)
+        elif batched_preprocess:
+            # decode raw uint8 on host, resize+convert whole batches at once
+            from ..kernels import preprocess as kpre
 
-        ds = ds.map(record_len).ignore_errors()
-        ds = ds.batch(batch_size, drop_remainder=True)
-    elif batched_preprocess:
-        # decode raw uint8 on host, resize+convert whole batches at once
-        from ..kernels import preprocess as kpre
+            if labels_per_shard is not None:
+                def decode_raw(item):
+                    view, label = item
+                    return (records.decode_image(view, copy=False),
+                            np.int32(label))
+            else:
+                def decode_raw(view):
+                    return records.decode_image(view, copy=False)
 
-        if labels_per_shard is not None:
-            def decode_raw(item):
-                view, label = item
-                return records.decode_image(view, copy=False), np.int32(label)
+            ds = ds.map(decode_raw, num_parallel_calls=num_parallel_calls)
+            ds = ds.ignore_errors()
+            ds = ds.batch(batch_size, drop_remainder=True)
+
+            def batch_resize(batch):
+                imgs = batch[0] if labels_per_shard is not None else batch
+                # with the kernel: the uint8 batch's copy to the device and
+                # the kernel's dispatch, on the pipeline's thread
+                with trace.span(trace.STAGE_DEVICE_PREPROCESS,
+                                batched_preprocess, imgs.nbytes):
+                    out = kpre.resize_convert(imgs, *out_hw,
+                                              backend=batched_preprocess)
+                if labels_per_shard is not None:
+                    return out, batch[1]
+                return out
+
+            ds = ds.map(batch_resize)
         else:
-            def decode_raw(view):
-                return records.decode_image(view, copy=False)
+            if labels_per_shard is not None:
+                def decode_into(item, out):
+                    view, label = item
+                    records.preprocess_image_into(view, out)
+                    return np.int32(label)
+            else:
+                def decode_into(view, out):
+                    records.preprocess_image_into(view, out)
+                    return None
 
-        ds = ds.map(decode_raw, num_parallel_calls=num_parallel_calls)
-        ds = ds.ignore_errors()
-        ds = ds.batch(batch_size, drop_remainder=True)
+            ds = ds.map_and_batch(
+                decode_into, batch_size,
+                num_parallel_calls=num_parallel_calls,
+                out_shape=(*out_hw, channels), out_dtype=np.float32,
+                ignore_errors=True, drop_remainder=True)
 
-        def batch_resize(batch):
-            imgs = batch[0] if labels_per_shard is not None else batch
-            # with the kernel: the uint8 batch's copy to the device and the
-            # kernel's dispatch, on the pipeline's thread
-            with trace.span(trace.STAGE_DEVICE_PREPROCESS,
-                            batched_preprocess, imgs.nbytes):
-                out = kpre.resize_convert(imgs, *out_hw,
-                                          backend=batched_preprocess)
-            return (out, batch[1]) if labels_per_shard is not None else out
+        if prefetch:
+            ds = ds.prefetch(prefetch)
+        return ds
 
-        ds = ds.map(batch_resize)
-    else:
-        if labels_per_shard is not None:
-            def decode_into(item, out):
-                view, label = item
-                records.preprocess_image_into(view, out)
-                return np.int32(label)
-        else:
-            def decode_into(view, out):
-                records.preprocess_image_into(view, out)
-                return None
+    def interleave(start: Optional[InterleaveState] = None) -> Dataset:
+        return src.interleave(
+            stream_shard, cycle_length=cycle_length,
+            block_length=block_length, num_parallel_calls=num_parallel_calls,
+            quarantine=quarantine, start=start)
 
-        ds = ds.map_and_batch(
-            decode_into, batch_size, num_parallel_calls=num_parallel_calls,
-            out_shape=(*out_hw, channels), out_dtype=np.float32,
-            ignore_errors=True, drop_remainder=True)
+    ds = decode_and_batch(interleave())
+    if labels_per_shard is None or quarantine is not None \
+            or scheduler is not None or repeat:
+        return ds
 
-    if prefetch:
-        ds = ds.prefetch(prefetch)
-    return ds
+    def seek(k: int) -> Dataset:
+        # the shard()/shuffle(seed) order replayed in pure Python, each
+        # shard's count from its labels: batch k begins at record k * B
+        counts = [len(labels) for _, labels in src]
+        return decode_and_batch(interleave(interleave_state(
+            counts, cycle_length, block_length, k * batch_size)))
+
+    return Dataset(ds._gen_fn, seek)

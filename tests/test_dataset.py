@@ -1,4 +1,5 @@
 """tf.data-like pipeline semantics (paper §II-A)."""
+import tempfile
 import threading
 import time
 
@@ -135,7 +136,9 @@ class TestCachePrefetch:
 # O(1) iterator resume (PR 10): interleave_order replica, seekable shard
 # streaming, ResumableIterator seek
 # ---------------------------------------------------------------------------
+from repro.core import records
 from repro.core.dataset import (ResumableIterator, interleave_order,
+                                interleave_state, sharded_image_pipeline,
                                 sharded_record_dataset)
 from repro.core.faults import FaultyStorage
 from repro.core.storage import NativeStorage
@@ -174,6 +177,64 @@ class TestInterleaveOrder:
             interleave_order([1], cycle_length=0)
         with pytest.raises(ValueError):
             interleave_order([1], block_length=0)
+
+    def _resumed(self, counts, cyc, blk, offset, npc=0):
+        """The real interleave started from ``interleave_state`` at
+        ``offset``: (its stream, the sources it opened)."""
+        opened = []
+
+        def sub(s):
+            opened.append(s)
+            return iter([(s, i) for i in range(counts[s])])
+
+        ds = Dataset.from_tensor_slices(list(range(len(counts)))).interleave(
+            sub, cycle_length=cyc, block_length=blk, num_parallel_calls=npc,
+            start=interleave_state(counts, cyc, blk, offset))
+        return list(ds), opened
+
+    def _check_every_offset(self, counts, cyc, blk, npc=0):
+        order = interleave_order(counts, cyc, blk)
+        for offset in range(len(order) + 2):
+            tail, opened = self._resumed(counts, cyc, blk, offset, npc)
+            assert tail == order[offset:], f"offset={offset}"
+            # a source whose elements all lie before the cut is never
+            # opened; an empty one is, as in the full stream, if it enters
+            # the cycle after the cut
+            entered = interleave_state(counts, cyc, blk, offset).entered
+            wanted = {s for s, _ in tail} | {
+                s for s in range(entered, len(counts)) if counts[s] == 0}
+            assert sorted(opened) == sorted(wanted), f"offset={offset}"
+
+    @pytest.mark.parametrize("npc", [0, 3])
+    @pytest.mark.parametrize("counts,cyc,blk", [
+        ([4, 4, 4], 2, 2),        # exact block multiples (empty-turn case)
+        ([5, 3, 7, 2, 6], 3, 2),  # uneven tails
+        ([8], 4, 3),              # single source, cycle > sources
+        ([0, 3, 4], 2, 2),        # empty source retires on first turn
+        ([3, 0, 0, 5, 1], 2, 3),
+        ([6, 6], 1, 4),           # degenerate cycle: pure concatenation
+    ])
+    def test_state_resumes_real_interleave(self, counts, cyc, blk, npc):
+        self._check_every_offset(counts, cyc, blk, npc)
+
+    @given(st.lists(st.integers(0, 9), min_size=1, max_size=6),
+           st.integers(1, 5), st.integers(1, 4))
+    @settings(max_examples=30, deadline=None)
+    def test_state_resumes_real_interleave_property(self, counts, cyc, blk):
+        self._check_every_offset(counts, cyc, blk)
+
+    def test_state_past_end_is_empty(self):
+        state = interleave_state([3, 2], 2, 2, 5)
+        assert state.cycle == () and state.entered == 2
+        assert self._resumed([3, 2], 2, 2, 99) == ([], [])
+
+    def test_start_refuses_quarantine(self):
+        from repro.core.dataset import ShardQuarantine
+
+        with pytest.raises(ValueError):
+            Dataset.range(2).interleave(
+                lambda s: iter([s]), quarantine=ShardQuarantine(),
+                start=interleave_state([1, 1], 4, 1, 1))
 
 
 class TestShardedRecordDataset:
@@ -218,6 +279,112 @@ class TestShardedRecordDataset:
         a = list(sharded_record_dataset(tmp_storage, paths, self.REC, seed=0))
         b = list(sharded_record_dataset(tmp_storage, paths, self.REC, seed=5))
         assert sorted(a) == sorted(b) and a != b
+
+
+# shard record counts: ragged, one short shard (index 2, which worker 0 of
+# two keeps) and counts that cycle_length/block_length do and do not divide
+SEEK_COUNTS = [6, 7, 2, 6, 5, 6, 6, 6]
+SEEK_BATCH = 4
+
+
+@pytest.fixture(scope="module")
+def ragged_corpus():
+    with tempfile.TemporaryDirectory() as d:
+        storage = NativeStorage(d)
+        paths, labels = [], []
+        for j, n in enumerate(SEEK_COUNTS):
+            p, ls = records.write_sharded_image_dataset(
+                storage, n, n, mean_hw=(12, 12), hw_jitter=0.0,
+                n_classes=7, seed=j, prefix=f"s{j}")
+            paths += p
+            labels += ls
+        yield storage, paths, labels
+
+
+def _seed_placing(short: int, early: bool, num_shards: int) -> int:
+    """A shuffle seed that puts shard ``short`` first or last among the
+    shards worker 0 streams."""
+    kept = list(range(len(SEEK_COUNTS)))[::num_shards]
+    for seed in range(1000):
+        order = list(Dataset.from_tensor_slices(kept).shuffle(len(SEEK_COUNTS),
+                                                              seed=seed))
+        if order[0 if early else -1] == short:
+            return seed
+    raise AssertionError("no seed places the short shard")
+
+
+def _assert_batches_equal(got, want):
+    assert len(got) == len(want)
+    for a, b in zip(got, want):
+        for x, y in zip(a, b):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+class TestShardedImagePipelineSeek:
+    """``sharded_image_pipeline(...).seek(k)`` is the full stream from batch
+    ``k`` on, positioned arithmetically."""
+
+    def _pipeline(self, storage, paths, labels, seed, cyc, blk, npc,
+                  num_shards, batched, **kw):
+        return sharded_image_pipeline(
+            storage, paths, labels, batch_size=SEEK_BATCH, cycle_length=cyc,
+            block_length=blk, num_parallel_calls=npc, out_hw=(8, 8),
+            seed=seed, num_shards=num_shards, shard_index=0,
+            batched_preprocess=batched, **kw)
+
+    @pytest.mark.parametrize("batched", [None, "numpy"])
+    @pytest.mark.parametrize("num_shards", [1, 2])
+    @pytest.mark.parametrize("npc", [0, 4])
+    @pytest.mark.parametrize("cyc,blk", [(2, 3), (3, 4), (4, 2)])
+    @pytest.mark.parametrize("early", [True, False])
+    def test_seek_equals_full_stream_tail(self, ragged_corpus, early, cyc,
+                                          blk, npc, num_shards, batched):
+        storage, paths, labels = ragged_corpus
+        seed = _seed_placing(2, early, num_shards)
+        ds = self._pipeline(storage, paths, labels, seed, cyc, blk, npc,
+                            num_shards, batched)
+        assert ds.seekable
+        full = list(ds)
+        assert len(full) == sum(SEEK_COUNTS[::num_shards]) // SEEK_BATCH
+        for k in range(len(full) + 2):
+            _assert_batches_equal(list(ds.seek(k)), full[k:])
+
+    @pytest.mark.parametrize("cyc,blk", [(2, 3), (3, 4)])
+    def test_seek_reads_no_shard_before_the_cut(self, ragged_corpus, cyc,
+                                                blk):
+        storage, paths, labels = ragged_corpus
+        seed = _seed_placing(2, True, 1)
+        shuffled = list(Dataset.from_tensor_slices(
+            list(range(len(paths)))).shuffle(len(paths), seed=seed))
+        order = interleave_order([SEEK_COUNTS[i] for i in shuffled], cyc, blk)
+        full = list(self._pipeline(storage, paths, labels, seed, cyc, blk, 4,
+                                   1, None))
+        for k in range(1, len(full) + 1):
+            counting = FaultyStorage(storage)  # unarmed: just an op log
+            tail = list(self._pipeline(counting, paths, labels, seed, cyc,
+                                       blk, 4, 1, None).seek(k))
+            _assert_batches_equal(tail, full[k:])
+            read = [p for op, p, _ in counting.op_log
+                    if op.startswith("read")]
+            wanted = {paths[shuffled[s]]
+                      for s, _ in order[k * SEEK_BATCH:]}
+            assert sorted(read) == sorted(wanted), f"k={k}"
+
+    def test_no_seek_without_counts_or_with_quarantine(self, ragged_corpus):
+        from repro.core.dataset import ShardQuarantine
+
+        storage, paths, labels = ragged_corpus
+        assert not sharded_image_pipeline(storage, paths).seekable
+        assert sharded_image_pipeline(storage, paths).seek(1) is None
+        quarantined = sharded_image_pipeline(
+            storage, paths, labels, quarantine=ShardQuarantine())
+        assert not quarantined.seekable
+        assert quarantined.seek(1) is None
+        assert not sharded_image_pipeline(storage, paths, labels,
+                                          repeat=True).seekable
+        # transformations do not carry the seek forward
+        ds = sharded_image_pipeline(storage, paths, labels)
+        assert ds.seekable and not ds.take(1).seekable
 
 
 class TestResumableIteratorSeek:
@@ -274,6 +441,70 @@ class TestResumableIteratorSeek:
         it.restore_state({"epoch": 0, "offset": len(self.DATA[0]),
                           "version": 1})
         assert next(it) == self.DATA[1][0]
+
+    def _image_factory(self, corpus, replay=False):
+        """Shaped like a training job's: ``ep -> pipeline``, no start.
+        ``replay``: a transformation drops the pipeline's seek."""
+        storage, paths, labels = corpus
+
+        def epoch(ep):
+            ds = sharded_image_pipeline(
+                storage, paths, labels, batch_size=SEEK_BATCH,
+                cycle_length=3, block_length=4, num_parallel_calls=2,
+                out_hw=(8, 8), seed=ep)
+            return ds.take(10 ** 6) if replay else ds
+        return epoch
+
+    def test_image_factory_without_start_restores_by_seek(self,
+                                                          ragged_corpus):
+        from repro import metrics
+
+        state = {"epoch": 1, "offset": 5, "version": 1}
+        replayed = ResumableIterator(self._image_factory(ragged_corpus,
+                                                         replay=True))
+        replayed.restore_state(state)
+        it = ResumableIterator(self._image_factory(ragged_corpus))
+        assert "seek" not in it.state()     # the factory takes no start
+        reg = metrics.start()
+        try:
+            it.restore_state(state)
+            counters = reg.collect()["counters"]
+        finally:
+            metrics.stop()
+        assert sum(v for k, v in counters.items()
+                   if k.startswith("pipeline.resume_seeks")) == 1
+        assert not any(k.startswith("pipeline.resume_skipped")
+                       for k in counters)
+        assert it.state() == state
+        _assert_batches_equal([next(it) for _ in range(3)],
+                              [next(replayed) for _ in range(3)])
+        it.close()
+        replayed.close()
+
+    def test_image_seek_traces_one_resume_seek_span(self, ragged_corpus):
+        from repro import trace
+
+        it = ResumableIterator(self._image_factory(ragged_corpus))
+        tracer = trace.start()
+        try:
+            it.restore_state({"epoch": 0, "offset": 4, "version": 1})
+        finally:
+            trace.stop()
+        it.close()
+        names = [s.name for s in tracer.spans()
+                 if s.name.startswith("resume_")]
+        assert names == ["resume_seek:4@epoch0"]
+
+    def test_image_seek_past_epoch_end_rolls_epoch(self, ragged_corpus):
+        fresh = ResumableIterator(self._image_factory(ragged_corpus))
+        n = len(list(self._image_factory(ragged_corpus)(0)))
+        it = ResumableIterator(self._image_factory(ragged_corpus))
+        it.restore_state({"epoch": 0, "offset": n, "version": 1})
+        fresh.restore_state({"epoch": 1, "offset": 0, "version": 1})
+        _assert_batches_equal([next(it)], [next(fresh)])
+        assert it.state()["epoch"] == 1
+        it.close()
+        fresh.close()
 
     def test_e2e_sharded_factory_seek_without_replay_io(self, tmp_storage):
         rec = 8

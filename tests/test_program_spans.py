@@ -164,10 +164,12 @@ def test_resume_spans_in_order(run):
     assert len(restores) == 1
     assert validate.t0 + validate.dur <= restores[0].t0
     assert restores[0].t0 + restores[0].dur <= seek.t0
-    # the seek replays the two batches of epoch 1 the preempted run took
-    replay, = _of(spans, trace.STAGE_DATA_WAIT, tid=tid,
-                  name="resume_skip:2@epoch1")
-    assert _inside(replay, seek)
+    # the seek places the pipeline past the two batches of epoch 1 the
+    # preempted run took, without replaying them
+    placed, = _of(spans, trace.STAGE_DATA_WAIT, tid=tid,
+                  name="resume_seek:2@epoch1")
+    assert _inside(placed, seek)
+    assert not [s for s in spans if s.name.startswith("resume_skip")]
 
 
 def test_device_preprocess_carries_the_batch_bytes(run):

@@ -466,6 +466,76 @@ class TestPipelineRetryAndQuarantine:
         assert rs.gave_up == 1  # the drop happened only after the budget
 
 
+class TestImagePipelineResume:
+    def test_asyncbb_preempt_mid_epoch_resumes_by_seek(self):
+        """A factory with no start offset, as a training job writes it:
+        the resume seeks the image pipeline (no replay) and the resumed
+        run neither skips nor repeats a sample, across the epoch's end."""
+        from repro import metrics
+        from repro.core import records, sharded_image_pipeline
+        from repro.train.trainer import Trainer
+
+        with tempfile.TemporaryDirectory() as d0, \
+                tempfile.TemporaryDirectory() as d1, \
+                tempfile.TemporaryDirectory() as d2:
+            corpus = NativeStorage(d0)
+            paths, labels = records.write_sharded_image_dataset(
+                corpus, 40, 6, mean_hw=(12, 12), hw_jitter=0.0, seed=1)
+
+            def epoch(ep):
+                return sharded_image_pipeline(
+                    corpus, paths, labels, batch_size=4, cycle_length=3,
+                    block_length=4, num_parallel_calls=2, out_hw=(8, 8),
+                    seed=ep)
+
+            golden = list(ResumableIterator(epoch, epochs=2))  # 2 x 10
+            seen = []
+
+            def step(state, batch):
+                seen.append(batch)
+                return ({"w": state["w"] + np.float64(1.0),
+                         "step": state["step"] + np.int64(1)},
+                        {"loss": np.float64(0.0)})
+
+            def manager():
+                return CheckpointManager(
+                    NativeStorage(d2), PREFIX, engine="asyncbb",
+                    fast_storage=NativeStorage(d1), max_pending=2)
+
+            state = {"w": np.float64(0.0), "step": np.int64(0)}
+            mgr = manager()
+            tr = Trainer(step, state, ResumableIterator(epoch, epochs=2),
+                         checkpointer=mgr, ckpt_every=3, resume=False)
+            tr.run(4)
+            tr.ckpt_every = 0           # the stop makes the fifth's save
+            tr.preempt(30.0)
+            tr.run(1)
+            tr.close()
+            mgr.close()
+            assert len(seen) == 5
+
+            reg = metrics.start()
+            try:
+                mgr2 = manager()
+                tr2 = Trainer(step, state, ResumableIterator(epoch, epochs=2),
+                              checkpointer=mgr2, resume=True)
+                counters = reg.collect()["counters"]
+            finally:
+                metrics.stop()
+            assert tr2.recovered_step == 5
+            assert sum(v for k, v in counters.items()
+                       if k.startswith("pipeline.resume_seeks")) == 1
+            assert not any(k.startswith("pipeline.resume_skipped")
+                           for k in counters)
+            tr2.run(20)                 # to the end of the second epoch
+            tr2.close()
+            mgr2.close()
+        assert len(seen) == len(golden) == 20
+        for (a_img, a_lbl), (b_img, b_lbl) in zip(seen, golden):
+            np.testing.assert_array_equal(a_img, b_img)
+            np.testing.assert_array_equal(a_lbl, b_lbl)
+
+
 # ---------------------------------------------------------------------------
 # ResumableIterator semantics
 # ---------------------------------------------------------------------------
