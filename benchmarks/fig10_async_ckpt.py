@@ -2,7 +2,7 @@
 
 The paper's burst buffer (§III-C/V-C) shrinks checkpoint stalls by staging
 on a fast tier — but training still blocks for the full fast-tier write.
-``AsyncCheckpointer`` blocks only for the host snapshot and overlaps the
+An ``"async"`` snapshot blocks only for the host copy and overlaps the
 entire sharded write with training (the write-side analogue of the paper's
 prefetcher result: complete compute/input overlap).
 
@@ -10,11 +10,13 @@ Protocol: N_ITERS synthetic training iterations (fixed COMPUTE_S compute
 slices under trace spans), checkpoint every CKPT_EVERY.  For each tier in
 hdd/ssd/optane/lustre compare:
 
-* ``direct``  — synchronous :class:`DirectCheckpointer` to the tier;
-* ``bb``      — :class:`BurstBufferCheckpointer`, optane stage + multi-stream
-  drain to the tier;
-* ``async``   — :class:`AsyncCheckpointer` to the tier (4 shards, parallel
-  shard writes).
+* ``direct``  — synchronous save to the tier;
+* ``bb``      — synchronous optane stage + multi-stream drain to the tier;
+* ``async``   — async snapshot to the tier (4 shards, parallel shard
+  writes).
+
+Each is the one :class:`~repro.core.engine.CheckpointEngine`, set by its
+snapshot mode and its tiers.
 
 Emits per-run rows (runtime, training-thread blocked seconds, checkpoint
 bytes, and the checkpoint-write/compute overlap ratio measured from the
@@ -29,8 +31,7 @@ import time
 
 from repro import trace
 from repro.core import make_storage
-from repro.core.async_checkpoint import AsyncCheckpointer
-from repro.core.burst_buffer import BurstBufferCheckpointer, DirectCheckpointer
+from repro.core.engine import CheckpointEngine
 
 from .common import RESULTS_DIR, SCRATCH, emit
 
@@ -92,16 +93,16 @@ def run() -> None:
 
         for tier in TIERS:
             runs = {
-                "direct": lambda: DirectCheckpointer(
-                    storage(f"direct_{tier}", tier), "ck/m",
+                "direct": lambda: CheckpointEngine(
+                    (storage(f"direct_{tier}", tier),), "ck/m",
                     n_shards=4, io_threads=4),
-                "bb": lambda: BurstBufferCheckpointer(
-                    storage(f"bb_fast_{tier}", "optane"),
-                    storage(f"bb_slow_{tier}", tier), "ck/m",
+                "bb": lambda: CheckpointEngine(
+                    (storage(f"bb_fast_{tier}", "optane"),
+                     storage(f"bb_slow_{tier}", tier)), "ck/m",
                     n_shards=4, io_threads=4, drain_streams=4),
-                "async": lambda: AsyncCheckpointer(
-                    storage(f"async_{tier}", tier), "ck/m",
-                    n_shards=4, io_threads=4),
+                "async": lambda: CheckpointEngine(
+                    (storage(f"async_{tier}", tier),), "ck/m",
+                    snapshot="async", n_shards=4, io_threads=4),
             }
             for strategy, make_ck in runs.items():
                 tracer = trace.start()

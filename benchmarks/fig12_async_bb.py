@@ -5,14 +5,16 @@ on training-thread blocked time; this benchmark closes the matrix with the
 fused engine.  For each slow tier in hdd/ssd/optane/lustre, run the same
 synthetic training loop under four strategies:
 
-* ``direct``   — synchronous :class:`DirectCheckpointer` to the tier;
-* ``bb``       — :class:`BurstBufferCheckpointer` (optane stage, blocking,
-  + multi-stream background drain to the tier);
-* ``async``    — :class:`AsyncCheckpointer` straight to the tier (snapshot
-  blocks, sharded write in background);
-* ``asyncbb``  — :class:`AsyncBurstBufferCheckpointer` (snapshot blocks;
-  optane stage *and* the intra-file parallel drain both run in
-  background threads).
+* ``direct``   — synchronous save to the tier;
+* ``bb``       — optane stage, blocking, + multi-stream background drain
+  to the tier;
+* ``async``    — straight to the tier (snapshot blocks, sharded write in
+  background);
+* ``asyncbb``  — snapshot blocks; optane stage *and* the intra-file
+  parallel drain both run in background threads.
+
+Each is the one :class:`~repro.core.engine.CheckpointEngine`, set by its
+snapshot mode (``"sync"``/``"async"``) and its tiers (one or two).
 
 Per strategy/tier we emit runtime, total training-thread blocked seconds,
 post-loop drain time, effective steps/s, and the checkpoint/compute overlap
@@ -40,9 +42,7 @@ sys.path.insert(0, "src")
 
 from repro import trace
 from repro.core import make_storage
-from repro.core.async_burst_buffer import AsyncBurstBufferCheckpointer
-from repro.core.async_checkpoint import AsyncCheckpointer
-from repro.core.burst_buffer import BurstBufferCheckpointer, DirectCheckpointer
+from repro.core.engine import CheckpointEngine
 
 from .common import RESULTS_DIR, SCRATCH, emit
 
@@ -102,22 +102,22 @@ def run(n_iters=9, ckpt_every=3, compute_s=0.05, state_layers=4,
 
         for tier in TIERS:
             makers = {
-                "direct": lambda: DirectCheckpointer(
-                    storage(f"direct_{tier}", tier), "ck/m",
+                "direct": lambda: CheckpointEngine(
+                    (storage(f"direct_{tier}", tier),), "ck/m",
                     n_shards=4, io_threads=4),
-                "bb": lambda: BurstBufferCheckpointer(
-                    storage(f"bb_fast_{tier}", "optane"),
-                    storage(f"bb_slow_{tier}", tier), "ck/m",
+                "bb": lambda: CheckpointEngine(
+                    (storage(f"bb_fast_{tier}", "optane"),
+                     storage(f"bb_slow_{tier}", tier)), "ck/m",
                     n_shards=4, io_threads=4, drain_streams=4,
                     drain_chunk=1 << 20),
-                "async": lambda: AsyncCheckpointer(
-                    storage(f"async_{tier}", tier), "ck/m",
-                    n_shards=4, io_threads=4),
-                "asyncbb": lambda: AsyncBurstBufferCheckpointer(
-                    storage(f"abb_fast_{tier}", "optane"),
-                    storage(f"abb_slow_{tier}", tier), "ck/m",
-                    n_shards=4, io_threads=4, drain_streams=4,
-                    drain_chunk=1 << 20),
+                "async": lambda: CheckpointEngine(
+                    (storage(f"async_{tier}", tier),), "ck/m",
+                    snapshot="async", n_shards=4, io_threads=4),
+                "asyncbb": lambda: CheckpointEngine(
+                    (storage(f"abb_fast_{tier}", "optane"),
+                     storage(f"abb_slow_{tier}", tier)), "ck/m",
+                    snapshot="async", n_shards=4, io_threads=4,
+                    drain_streams=4, drain_chunk=1 << 20),
             }
             per_tier = {}
             for strategy in STRATEGIES:

@@ -31,7 +31,7 @@ Two hdd-only sections ride along:
   chunks, and the save must still commit (``drain_stalls``/
   ``drain_aborts`` reported).
 * **fused-vs-bare overhead**: training-thread blocked time through the
-  fused manager vs a bare :class:`AsyncBurstBufferCheckpointer` —
+  fused manager vs the bare ``asyncbb`` engine —
   the lifecycle layer must cost <= 1.1x blocked (1.3x in --smoke,
   where ms-scale snapshots make the ratio noisy).
 
@@ -53,7 +53,7 @@ sys.path.insert(0, "src")
 import numpy as np
 
 from repro.core import make_storage
-from repro.core.async_burst_buffer import AsyncBurstBufferCheckpointer
+from repro.core.engine import CheckpointEngine
 from repro.core.dataset import Dataset, ResumableIterator
 from repro.core.faults import FaultyStorage
 from repro.core.recovery import CheckpointManager
@@ -194,9 +194,8 @@ def fused_overhead_section(root, state_mb, n_saves, reps=3):
 
     def bare(tag):
         fast, slow = tiers(tag)
-        return AsyncBurstBufferCheckpointer(fast, slow, PREFIX,
-                                            drain_streams=4,
-                                            drain_chunk=1 << 20)
+        return CheckpointEngine((fast, slow), PREFIX, snapshot="async",
+                                drain_streams=4, drain_chunk=1 << 20)
 
     def fused(tag):
         fast, slow = tiers(tag)

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from repro import metrics, trace
 from repro.configs import ALEXNET_SMOKE as CFG
 from repro.core import make_storage, records
-from repro.core.burst_buffer import BurstBufferCheckpointer
+from repro.core.engine import CheckpointEngine
 from repro.core.dataset import image_pipeline
 from repro.models import alexnet as A
 from repro.train.trainer import Trainer
@@ -80,8 +80,7 @@ def run(name: str = "fig8_trace") -> dict:
     ds = image_pipeline(data_st, paths, labels, batch_size=8,
                         num_parallel_calls=4, prefetch=2,
                         out_hw=(CFG.in_hw, CFG.in_hw), repeat=True)
-    ckpt = BurstBufferCheckpointer(fast_st, slow_st, "ckpt/model",
-                                   n_shards=2)
+    ckpt = CheckpointEngine((fast_st, slow_st), "ckpt/model", n_shards=2)
     tr = Trainer(train_step, state, iter(ds), checkpointer=ckpt,
                  ckpt_every=CKPT_EVERY, resume=False)
     tr.run(N_STEPS)

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 
 from repro.configs.alexnet_mini import AlexNetConfig
 from repro.core import make_storage
-from repro.core.burst_buffer import BurstBufferCheckpointer, DirectCheckpointer
+from repro.core.engine import CheckpointEngine
 from repro.core.dataset import image_pipeline
 from repro.core.stats import IOTracer
 from repro.models import alexnet as A
@@ -88,7 +88,7 @@ def run() -> None:
         rows.append(f"target=none,runtime_s={t:.2f},blocked_s=0")
 
         for name in ("hdd", "ssd", "optane"):
-            ck = DirectCheckpointer(tier(name), f"{name}/m", sync=True)
+            ck = CheckpointEngine((tier(name),), f"{name}/m", sync=True)
             t, _ = run_training(ck, data_st, paths, labels, step)
             runtimes[name] = t
             rows.append(f"target={name},runtime_s={t:.2f},"
@@ -99,7 +99,7 @@ def run() -> None:
         slow = make_storage("hdd", os.path.join(root, "hdd_bb"), slow_tr,
                             time_scale=CKPT_TIME_SCALE)
         tracers["hdd_bb"] = slow_tr
-        bb = BurstBufferCheckpointer(fast, slow, "bb/m", sync=True)
+        bb = CheckpointEngine((fast, slow), "bb/m", sync=True)
         t, drain = run_training(bb, data_st, paths, labels, step)
         runtimes["burst_buffer"] = t
         rows.append(f"target=burst_buffer,runtime_s={t:.2f},"

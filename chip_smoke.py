@@ -253,7 +253,7 @@ def four_chips(corpus, cfg, ckpt_root: str, devices, *, seed: int,
 
         shardings = jax.tree.map(
             lambda leaf: NamedSharding(mesh, spec(leaf)), dp.state)
-        restored = mgr.saver.restore_sharded(dp.state, shardings)
+        restored = mgr.restore_sharded(dp.state, shardings)
         for leaf, sh in zip(jax.tree.leaves(restored),
                             jax.tree.leaves(shardings)):
             if leaf.sharding != sh:

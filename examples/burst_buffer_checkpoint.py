@@ -6,22 +6,20 @@
 Checkpoints a ~75MB state to (a) direct HDD, (b) direct Optane, (c) Optane
 burst buffer with multi-stream async HDD drain, printing blocked time per
 strategy and proving the slow tier ends up with every checkpoint.  With
-``--async``, also runs the two async engines: the
-:class:`AsyncCheckpointer` (training blocks only for the host snapshot —
-milliseconds — while the sharded write to HDD runs on a background writer
-thread) and the fused :class:`AsyncBurstBufferCheckpointer` (snapshot
-blocks; the Optane stage *and* the intra-file parallel HDD drain both run
-in background threads, so not even the fast-tier write is paid by the
-training thread).
+``--async``, also runs the two async presets of the one
+:class:`CheckpointEngine`: ``async`` (training blocks only for the host
+snapshot — milliseconds — while the sharded write to HDD runs on a
+background writer thread) and ``asyncbb`` (snapshot blocks; the Optane
+stage *and* the intra-file parallel HDD drain both run in background
+threads, so not even the fast-tier write is paid by the training thread).
+Each is built through :class:`CheckpointManager`.
 """
 import os, sys, tempfile, time
 sys.path.insert(0, "src")
 
 import numpy as np
 
-from repro.core import (AsyncBurstBufferCheckpointer, AsyncCheckpointer,
-                        BurstBufferCheckpointer, DirectCheckpointer,
-                        make_storage)
+from repro.core import CheckpointManager, make_storage
 from repro.core.checkpoint import CheckpointSaver
 
 
@@ -36,18 +34,18 @@ def main():
     ts = 1.0
 
     hdd = make_storage("hdd", os.path.join(root, "hdd"), time_scale=ts)
-    d = DirectCheckpointer(hdd, "direct_hdd/m")
+    d = CheckpointManager(hdd, "direct_hdd/m")
     d.save(1, state)
     print(f"direct-to-HDD blocked:    {d.blocked_s[0]:.2f}s")
 
     opt = make_storage("optane", os.path.join(root, "opt"), time_scale=ts)
-    d2 = DirectCheckpointer(opt, "direct_opt/m")
+    d2 = CheckpointManager(opt, "direct_opt/m")
     d2.save(1, state)
     print(f"direct-to-Optane blocked: {d2.blocked_s[0]:.2f}s")
 
     fast = make_storage("optane", os.path.join(root, "bb_fast"), time_scale=ts)
     slow = make_storage("hdd", os.path.join(root, "bb_slow"), time_scale=ts)
-    bb = BurstBufferCheckpointer(fast, slow, "bb/m")
+    bb = CheckpointManager(slow, "bb/m", engine="bb", fast_storage=fast)
     t0 = time.monotonic()
     bb.save(1, state)
     print(f"burst-buffer blocked:     {bb.blocked_s[0]:.2f}s "
@@ -63,7 +61,7 @@ def main():
     if run_async:
         ahdd = make_storage("hdd", os.path.join(root, "async_hdd"),
                             time_scale=ts)
-        ac = AsyncCheckpointer(ahdd, "async/m", n_shards=4)
+        ac = CheckpointManager(ahdd, "async/m", engine="async", n_shards=4)
         t0 = time.monotonic()
         handle = ac.save(1, state)
         print(f"async blocked:            {ac.blocked_s[0]:.2f}s "
@@ -80,8 +78,9 @@ def main():
                              time_scale=ts)
         aslow = make_storage("hdd", os.path.join(root, "abb_slow"),
                              time_scale=ts)
-        abb = AsyncBurstBufferCheckpointer(afast, aslow, "abb/m",
-                                           n_shards=4, drain_streams=4)
+        abb = CheckpointManager(aslow, "abb/m", engine="asyncbb",
+                                fast_storage=afast, n_shards=4,
+                                drain_streams=4)
         t0 = time.monotonic()
         handle = abb.save(1, state)
         print(f"async-bb blocked:         {abb.blocked_s[0]:.2f}s "

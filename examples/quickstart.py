@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS
-from repro.core import BurstBufferCheckpointer, Dataset, make_storage
+from repro.core import CheckpointManager, Dataset, make_storage
 from repro.core import records
 from repro.launch.compile_cache import use_compile_cache
 from repro.train import steps as S
@@ -52,7 +52,8 @@ def main():
     # 3. burst-buffer checkpointing: optane stage, hdd archive
     fast = make_storage("optane", os.path.join(root, "bb"), time_scale=0.05)
     slow = make_storage("hdd", os.path.join(root, "archive"), time_scale=0.05)
-    ckpt = BurstBufferCheckpointer(fast, slow, "ckpt/quickstart")
+    ckpt = CheckpointManager(slow, "ckpt/quickstart", engine="bb",
+                             fast_storage=fast)
 
     # 4. train
     state = S.init_train_state(jax.random.PRNGKey(0), cfg, opt)
@@ -64,7 +65,7 @@ def main():
     print(f"step {tr.step}: loss {hist[0]['loss']:.3f} -> {hist[-1]['loss']:.3f}")
     print("report:", {k: v for k, v in tr.report().items() if k != 'timer'})
     print("archived checkpoint steps on slow tier:",
-          [d.step for d in ckpt.drains])
+          [d.step for d in ckpt.engine.drains])
     ckpt.close()
 
 

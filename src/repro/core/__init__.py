@@ -13,15 +13,12 @@
   simulator: hdd / ssd / optane / lustre).
 * :mod:`repro.core.checkpoint` — sharded TF-Saver-like checkpointing with
   parallel shard I/O (``io_threads``).
-* :mod:`repro.core.async_checkpoint` — async snapshot checkpointing:
-  training blocks for the host snapshot only; a background writer does the
-  sharded save; ``save()`` returns a future-like handle.
-* :mod:`repro.core.burst_buffer` — fast-tier staging + multi-stream async
-  drain (the 2.6x), with intra-file parallel range drains
-  (``Storage.write_range``).
-* :mod:`repro.core.async_burst_buffer` — the fused engine: snapshot-only
-  blocking, background fast-tier stage, then the multi-stream drain —
-  training never blocks past the host snapshot.
+* :mod:`repro.core.engine` — :class:`CheckpointEngine`, the one save
+  engine, set by its snapshot mode (``"sync"``: write on the caller's
+  thread; ``"async"``: block for the host snapshot only) and its tiers
+  (one, or a fast tier drained to a slow one on multiple streams — the
+  paper's 2.6x burst buffer); ``save()`` returns a future-like
+  :class:`SaveHandle`.
 * :mod:`repro.core.faults` — :class:`FaultyStorage` fault injection
   (sticky failures, torn writes, reordered fsync + crash, and non-sticky
   transients), the crash-consistency proof harness for all of the above.
@@ -34,7 +31,8 @@
   the transparent :class:`CachingStorage` wrapper, and the
   :class:`ReadaheadScheduler` that prefetches upcoming shards' blocks
   ahead of the interleave cursor.
-* :mod:`repro.core.recovery` — :class:`CheckpointManager`: retention
+* :mod:`repro.core.recovery` — :class:`CheckpointManager`, the engine's
+  four presets behind one checkpointer: retention
   (keep-last-k + keep-every-n), corruption-aware ``latest_valid()``
   restore, crash-safe GC, and TrainState-level ``resume()`` that also
   re-positions a :class:`~repro.core.dataset.ResumableIterator`.
@@ -58,10 +56,7 @@ from .prefetcher import PrefetchIterator, prefetch_to_device
 from .readerpool import ReaderPool, reader_pool
 from .storage import Storage, NativeStorage, SimulatedStorage, TIERS, make_storage
 from .checkpoint import CheckpointSaver, PreemptionReport
-from .async_checkpoint import AsyncCheckpointer, AsyncSaveHandle
-from .async_burst_buffer import AsyncBurstBufferCheckpointer
-from .burst_buffer import (BurstBufferCheckpointer, DirectCheckpointer,
-                           DrainStallError)
+from .engine import CheckpointEngine, DrainStallError, SaveHandle
 from .faults import FaultInjected, FaultyStorage, TransientFault
 from .retry import RetryPolicy, RetryingStorage
 from .recovery import CheckpointManager, ResumeResult, latest_valid_step, \
@@ -74,9 +69,8 @@ __all__ = [
     "BlockCache", "CachingStorage", "ReadaheadScheduler",
     "PrefetchIterator", "prefetch_to_device", "ReaderPool", "reader_pool",
     "Storage", "NativeStorage", "SimulatedStorage", "TIERS", "make_storage",
-    "CheckpointSaver", "PreemptionReport", "AsyncCheckpointer",
-    "AsyncSaveHandle", "AsyncBurstBufferCheckpointer",
-    "BurstBufferCheckpointer", "DirectCheckpointer", "DrainStallError",
+    "CheckpointSaver", "PreemptionReport", "CheckpointEngine", "SaveHandle",
+    "DrainStallError",
     "FaultInjected", "FaultyStorage", "TransientFault",
     "RetryPolicy", "RetryingStorage",
     "CheckpointManager", "ResumeResult", "latest_valid_step", "validate_step",

@@ -21,8 +21,8 @@ Guarantees:
 * **Parallel shard I/O** — the N data shards are written (and read back)
   concurrently on an ``io_threads`` pool, the write-side analogue of the
   paper's read thread-scaling (Fig. 4/5); ``save_flat`` takes an
-  already-snapshotted flat dict so :class:`repro.core.async_checkpoint.
-  AsyncCheckpointer` can run the whole write off the training thread.
+  already-snapshotted flat dict so :class:`repro.core.engine.
+  CheckpointEngine` can run the whole write off the training thread.
 * **int8 option** — blockwise-quantized storage (2x–4x smaller bursts), with
   scales stored alongside; see also ``repro.kernels.quantize`` for the TPU
   kernel version of the same transform.
@@ -462,13 +462,18 @@ class CheckpointSaver:
         """Elastic restore: place each tensor on the mesh given by
         ``shardings`` (pytree of NamedSharding matching ``skeleton``),
         regardless of the topology that wrote the checkpoint."""
-        import jax
+        return place_sharded(self.restore_pytree(skeleton, step), shardings)
 
-        restored = self.restore_pytree(skeleton, step)
 
-        def _place(arr, sharding):
-            return jax.make_array_from_callback(
-                arr.shape, sharding, lambda idx: arr[idx]
-            )
+def place_sharded(restored: Any, shardings: Any) -> Any:
+    """Place each host array of ``restored`` on the mesh of the matching
+    sharding in ``shardings``: the index is topology-free, so any mesh will
+    do."""
+    import jax
 
-        return jax.tree.map(_place, restored, shardings)
+    def _place(arr, sharding):
+        return jax.make_array_from_callback(
+            arr.shape, sharding, lambda idx: arr[idx]
+        )
+
+    return jax.tree.map(_place, restored, shardings)

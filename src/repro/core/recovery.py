@@ -25,9 +25,11 @@ actually work end-to-end:
   the trainer attached at save time (``extra_meta["pipeline"]``), so a
   resumed run neither skips nor replays samples.
 
-PR 10 fuses the manager with every save engine (``engine=direct|async|
-bb|asyncbb``): one lifecycle subsystem instead of "retention *or* the
-async blocked-time win".  Each step moves through explicit states —
+The manager fronts the one save engine, :class:`~repro.core.engine.
+CheckpointEngine`, under four preset names (``engine=direct|async|bb|
+asyncbb``, each a snapshot mode and a number of tiers): one lifecycle
+subsystem instead of "retention *or* the async blocked-time win".  Each
+step moves through explicit states —
 ``SNAPSHOTTED`` (host copy taken) → ``STAGED`` (durable at the engine's
 preemption tier) → ``COMMITTED`` (durable at the final tier) — with
 retention/GC **deferred past drain commit** via engine hooks, so a step
@@ -36,13 +38,10 @@ staged on the fast tier but not yet drained is never collected and
 deadline_s)`` forwards the graceful-shutdown budget to the engine
 (promote the newest in-flight save, abandon the rest, record it).
 
-The manager implements the checkpointer interface the
-:class:`~repro.train.trainer.Trainer` expects (``save``/``latest_step``/
-``restore_pytree``/``wait``/``close``/``preempt``/``blocked_s``), so it
-can drop in wherever a :class:`~repro.core.burst_buffer.
-DirectCheckpointer` does — optionally with a :class:`~repro.core.retry.
-RetryingStorage` wrap for transient-fault absorption
-(``retry_policy=...``).
+The manager is the checkpointer the :class:`~repro.train.trainer.Trainer`
+speaks to (``resume``/``save``/``preempt``/``wait``/``blocked_s``),
+optionally with a :class:`~repro.core.retry.RetryingStorage` wrap for
+transient-fault absorption (``retry_policy=...``).
 """
 from __future__ import annotations
 
@@ -55,16 +54,11 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Set, Tuple
 
 from .. import metrics, trace
-from .async_burst_buffer import AsyncBurstBufferCheckpointer
-from .async_checkpoint import AsyncCheckpointer
-from .burst_buffer import BurstBufferCheckpointer, DirectCheckpointer
 from .checkpoint import (CHECKPOINT_MARKER, CheckpointSaver,
-                         PreemptionReport, SaveResult, unflatten_pytree,
+                         PreemptionReport, place_sharded, unflatten_pytree,
                          write_marker)
+from .engine import NO_SAVER_GC, CheckpointEngine, SaveHandle
 from .retry import RetryingStorage, RetryPolicy
-
-#: Effectively-infinite retention for the inner saver: the manager owns GC.
-_NO_SAVER_GC = 1 << 30
 
 #: Per-step lifecycle states of the fused manager (monotonic order).
 SNAPSHOTTED = "SNAPSHOTTED"   # host snapshot taken; nothing on storage yet
@@ -73,7 +67,9 @@ COMMITTED = "COMMITTED"       # durable at the final (slow) tier; GC-eligible
 ABANDONED = "ABANDONED"       # given up by preempt() to meet its deadline
 _STATE_ORDER = {SNAPSHOTTED: 0, STAGED: 1, COMMITTED: 2}
 
-ENGINES = ("direct", "async", "bb", "asyncbb")
+#: Engine presets: name -> (snapshot mode, number of tiers).
+ENGINES = {"direct": ("sync", 1), "async": ("async", 1),
+           "bb": ("sync", 2), "asyncbb": ("async", 2)}
 #: How many COMMITTED entries the per-step state map keeps around (all
 #: non-committed entries are always kept — they are live lifecycle state).
 _STATE_HISTORY = 64
@@ -188,15 +184,16 @@ class ResumeResult:
 class CheckpointManager:
     """Retention + corruption-aware restore, fused with any save engine.
 
-    ``engine`` selects the save path (all four share one commit protocol):
+    ``engine`` names a preset of :class:`~repro.core.engine.
+    CheckpointEngine` (:data:`ENGINES`; all four share one commit
+    protocol):
 
     * ``"direct"`` (default) — synchronous sharded save to ``storage``;
-    * ``"async"`` — :class:`~repro.core.async_checkpoint.AsyncCheckpointer`
-      (snapshot-only blocking, background write);
-    * ``"bb"`` — :class:`~repro.core.burst_buffer.BurstBufferCheckpointer`
-      (stage to ``fast_storage``, background drain to ``storage``);
-    * ``"asyncbb"`` — the fused engine (snapshot-only blocking, background
-      stage *and* drain).
+    * ``"async"`` — snapshot-only blocking, background write;
+    * ``"bb"`` — stage to ``fast_storage``, background drain to
+      ``storage``;
+    * ``"asyncbb"`` — snapshot-only blocking, background stage *and*
+      drain.
 
     The manager drives every step through explicit lifecycle states
     (:data:`SNAPSHOTTED` → :data:`STAGED` → :data:`COMMITTED`, readable via
@@ -231,7 +228,6 @@ class CheckpointManager:
         quantize: Optional[str] = None,
         io_threads: Optional[int] = None,
         max_pending: int = 2,
-        cleanup_fast: bool = True,
         drain_streams: int = 4,
         drain_chunk: int = 8 << 20,
         drain_stall_timeout: Optional[float] = None,
@@ -242,8 +238,10 @@ class CheckpointManager:
         if keep_every is not None and keep_every < 1:
             raise ValueError(f"keep_every must be >= 1, got {keep_every}")
         if engine not in ENGINES:
-            raise ValueError(f"engine must be one of {ENGINES}, got {engine!r}")
-        if engine in ("bb", "asyncbb") and fast_storage is None:
+            raise ValueError(
+                f"engine must be one of {tuple(ENGINES)}, got {engine!r}")
+        snapshot, n_tiers = ENGINES[engine]
+        if n_tiers == 2 and fast_storage is None:
             raise ValueError(f"engine={engine!r} requires fast_storage")
         if retry_policy is not None:
             storage = RetryingStorage(storage, retry_policy)
@@ -252,7 +250,6 @@ class CheckpointManager:
         self.storage = storage
         self.fast_storage = fast_storage
         self.prefix = prefix
-        self.engine_kind = engine
         self.keep_last = keep_last
         self.keep_every = keep_every
         # the slow-tier saver never GCs (keep=inf) and is used for restore
@@ -260,34 +257,17 @@ class CheckpointManager:
         # where "valid" is a first-class concept
         saver_kw = dict(n_shards=n_shards, sync=sync, quantize=quantize,
                         io_threads=io_threads)
-        self.saver = CheckpointSaver(storage, prefix, keep=_NO_SAVER_GC,
+        self.saver = CheckpointSaver(storage, prefix, keep=NO_SAVER_GC,
                                      **saver_kw)
-        if engine == "direct":
-            self.engine = DirectCheckpointer(
-                storage, prefix, keep=_NO_SAVER_GC, **saver_kw)
-        elif engine == "async":
-            self.engine = AsyncCheckpointer(
-                storage, prefix, keep=_NO_SAVER_GC,
-                max_pending=max_pending, **saver_kw)
-            self.engine.on_committed = self._on_committed
-        elif engine == "bb":
-            self.engine = BurstBufferCheckpointer(
-                fast_storage, storage, prefix, keep=_NO_SAVER_GC,
-                cleanup_fast=cleanup_fast, drain_streams=drain_streams,
-                drain_chunk=drain_chunk,
-                drain_stall_timeout=drain_stall_timeout,
-                drain_requeue_limit=drain_requeue_limit, **saver_kw)
-        else:  # asyncbb
-            self.engine = AsyncBurstBufferCheckpointer(
-                fast_storage, storage, prefix, keep=_NO_SAVER_GC,
-                max_pending=max_pending, cleanup_fast=cleanup_fast,
-                drain_streams=drain_streams, drain_chunk=drain_chunk,
-                drain_stall_timeout=drain_stall_timeout,
-                drain_requeue_limit=drain_requeue_limit, **saver_kw)
-        if engine in ("bb", "asyncbb"):
-            self.engine.on_staged = self._on_staged
-            self.engine.on_drained = self._on_committed
-        self.fast_saver = getattr(self.engine, "fast_saver", None)
+        tiers = (fast_storage, storage) if n_tiers == 2 else (storage,)
+        self.engine = CheckpointEngine(
+            tiers, prefix, snapshot=snapshot, max_pending=max_pending,
+            drain_streams=drain_streams, drain_chunk=drain_chunk,
+            drain_stall_timeout=drain_stall_timeout,
+            drain_requeue_limit=drain_requeue_limit, **saver_kw)
+        self.engine.on_staged = self._on_staged
+        self.engine.on_drained = self._on_committed
+        self.fast_saver = self.engine.savers[0] if n_tiers == 2 else None
         self._dir, _ = _split_prefix(prefix)
         self.gc_deleted: List[int] = []  # every step GC ever removed
         self.abandoned_steps: List[int] = []  # given up by preempt()
@@ -338,24 +318,16 @@ class CheckpointManager:
         self.gc()
 
     # -- save + retention ------------------------------------------------------
-    def save(self, step: int, tree: Any, extra_meta: Optional[dict] = None):
-        """Save through the engine.  Returns its native result — a
-        :class:`~repro.core.checkpoint.SaveResult` for the synchronous
-        engines, an :class:`~repro.core.async_checkpoint.AsyncSaveHandle`
-        for the async ones."""
+    def save(self, step: int, tree: Any,
+             extra_meta: Optional[dict] = None) -> SaveHandle:
+        """Save through the engine; the handle settles at the first-tier
+        commit (already settled for a ``"sync"`` snapshot, whose hooks —
+        and so the inline GC of ``"direct"`` — ran inside the call)."""
         if self._closed:
             raise RuntimeError("save() on a closed CheckpointManager")
-        r = self.engine.save(step, tree, extra_meta)
-        self._mark(step, SNAPSHOTTED)
-        if self.engine_kind == "direct":
-            # synchronous single-tier commit: the save call was the whole
-            # lifecycle, and GC stays inline (back-compat with PR 8)
-            self._mark(step, STAGED)
-            self._on_committed(step)
-        elif self.engine_kind == "bb":
-            # save() blocked through the fast-tier write: already staged
-            self._mark(step, STAGED)
-        return r
+        handle = self.engine.save(step, tree, extra_meta)
+        self._mark(step, SNAPSHOTTED)  # a no-op once a hook moved it on
+        return handle
 
     def retained_steps(self) -> List[int]:
         """The set the current policy would keep, given what's on disk."""
@@ -480,6 +452,13 @@ class CheckpointManager:
         treedef = jax.tree_util.tree_structure(skeleton)
         return unflatten_pytree(flat, treedef)
 
+    def restore_sharded(self, skeleton: Any, shardings: Any,
+                        step: Optional[int] = None) -> Any:
+        """Elastic restore of ``step`` (or the newest restorable step), in
+        :meth:`restore`'s tier order, placed on the mesh of ``shardings``
+        (a pytree of shardings matching ``skeleton``)."""
+        return place_sharded(self.restore_pytree(skeleton, step), shardings)
+
     def resume(self, skeleton: Any, *, data_iter: Any = None,
                step: Optional[int] = None) -> ResumeResult:
         """TrainState-level restart: params + input-pipeline position.
@@ -539,9 +518,8 @@ class CheckpointManager:
     def close(self) -> None:
         """Idempotent shutdown.  The first call closes the engine and lets
         its never-delivered background error (if any) surface; later calls
-        are no-ops — the error is delivered exactly once, matching the
-        :class:`~repro.core.burst_buffer.DirectCheckpointer` close()
-        discipline even when the engine still has pending saves."""
+        are no-ops — the error is delivered exactly once, even when the
+        engine still has pending saves."""
         if self._closed:
             return
         self._closed = True

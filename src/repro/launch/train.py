@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs import ARCHS
-from ..core import BurstBufferCheckpointer, Dataset, DirectCheckpointer, make_storage
+from ..core import CheckpointManager, Dataset, make_storage
 from ..core import records
 from ..train import steps as S
 from ..train.optimizer import OptConfig
@@ -73,7 +73,8 @@ def main() -> None:
     fast = make_storage(args.ckpt_fast, os.path.join(root, "bb"), time_scale=0.05)
     slow = make_storage(args.ckpt_slow, os.path.join(root, "archive"),
                         time_scale=0.05)
-    ckpt = BurstBufferCheckpointer(fast, slow, f"ckpt/{cfg.name}")
+    ckpt = CheckpointManager(slow, f"ckpt/{cfg.name}", engine="bb",
+                             fast_storage=fast)
 
     state = S.init_train_state(jax.random.PRNGKey(0), cfg, opt)
     step = jax.jit(S.make_train_step(cfg, opt, None, remat=False,

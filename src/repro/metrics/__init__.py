@@ -23,8 +23,8 @@ Instrumented producers: ``core/readerpool.py`` (size, queue depth,
 in-flight), ``core/prefetcher.py`` (occupancy, producer stall, consumer
 wait), ``core/dataset.py`` (records, decode latency, drops),
 ``core/storage.py`` (+ ``faults.py``: per-tier ops/bytes/latency, injected
-faults), ``core/async_checkpoint.py`` / ``core/burst_buffer.py`` (pending
-saves, snapshot/write/drain latency, drain backlog bytes),
+faults), ``core/engine.py`` (pending saves, snapshot/write/drain latency, drain
+backlog bytes),
 ``train/trainer.py`` (per-step heartbeat + stall detection).
 
 Typical use::

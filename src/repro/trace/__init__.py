@@ -29,7 +29,7 @@ Subsystem map:
 Instrumented producers: ``core/storage.py`` (reads/writes, incl. simulated
 device pacing), ``core/dataset.py`` (per-element map/decode),
 ``core/prefetcher.py`` (background fetches + buffer-depth counter),
-``core/checkpoint.py`` (save/restore), ``core/burst_buffer.py`` (drains),
+``core/checkpoint.py`` (save/restore), ``core/engine.py`` (snapshot, stage, drains),
 ``train/trainer.py`` (per-step data-wait vs compute, and inside the step
 its dispatch and syncs; the save, the stop and the close),
 ``core/recovery.py`` (validation, restore, iterator seek on resume) and

@@ -54,7 +54,7 @@ STAGE_CKPT_SAVE = "ckpt_save"             # Trainer's save, pipeline state incl.
 STAGE_PIPELINE_STATE = "pipeline_state"   # iterator state() for the save meta
 STAGE_CKPT_BACKPRESSURE = "ckpt_backpressure"  # async save waiting for a slot
 STAGE_PREEMPT = "preempt"                 # final save and promote at a stop
-STAGE_PREEMPT_PROMOTE = "preempt_promote"  # engine preempt() / handle.result()
+STAGE_PREEMPT_PROMOTE = "preempt_promote"  # the checkpointer's preempt()
 STAGE_PIPELINE_CLOSE = "pipeline_close"   # Trainer.close of the data iterator
 STAGE_CKPT_CLOSE = "ckpt_close"           # CheckpointManager.close (joins)
 STAGE_CKPT_VALIDATE = "ckpt_validate"     # finding the newest valid step

@@ -4,27 +4,27 @@ Integrates the paper's pieces end-to-end:
 
 * data comes through the :mod:`repro.core.dataset` pipeline (parallel map +
   prefetch) and optionally :func:`prefetch_to_device`;
-* checkpoints go through a Direct-, BurstBuffer-, Async- or
-  AsyncBurstBuffer-checkpointer every ``ckpt_every`` steps (the paper's
-  protocol: §IV-C).  With an async engine
-  (:class:`repro.core.async_checkpoint.AsyncCheckpointer` or
-  :class:`repro.core.async_burst_buffer.AsyncBurstBufferCheckpointer`),
-  ``save()`` returns a future-like handle and the step loop never blocks
-  past the host snapshot; the trainer tracks in-flight handles, re-raises
-  background write failures at the next step boundary and at ``run()``
-  exit, and blocks on the final preemption save so the checkpoint is
-  durable (fast-tier committed, for the async burst buffer) before
-  stopping.  A save still in flight when ``run()`` returns stays pending —
-  call :meth:`Trainer.wait_for_checkpoints` to drain it and surface any
-  error (the same contract as ``BurstBufferCheckpointer.wait``);
-* **restart**: on construction the trainer restores the newest checkpoint
-  if one exists (crash/preemption recovery);
+* checkpoints go through one checkpointer every ``ckpt_every`` steps (the
+  paper's protocol: §IV-C), a :class:`repro.core.recovery.
+  CheckpointManager` or anything that forwards to one.  The trainer speaks
+  one interface and probes none of it: ``resume`` on construction,
+  ``save`` (which returns a future-like handle that settles at the
+  first-tier commit), ``preempt`` at a stop, ``wait`` to drain, and
+  ``blocked_s`` in :meth:`Trainer.report`;
+* **saves in flight**: with an async snapshot the step loop never blocks
+  past the host copy.  The trainer keeps the handles, re-raises a
+  background write failure at the next step boundary and at ``run()``
+  exit, and leaves a save still in flight when ``run()`` returns pending:
+  :meth:`Trainer.wait_for_checkpoints` drains it and surfaces its error;
+* **restart**: on construction the trainer resumes the newest restorable
+  checkpoint, params and input-pipeline position (crash/preemption
+  recovery);
 * **preemption**: SIGTERM (or :meth:`Trainer.preempt`) triggers
-  checkpoint-and-stop at the next step boundary; with a
-  ``preempt_deadline_s`` budget and an engine that supports
-  ``preempt()``, older queued snapshots are abandoned and the final save
-  is promoted to its durability tier within the deadline — the outcome
-  lands in :attr:`Trainer.preemption_report`;
+  checkpoint-and-stop at the next step boundary: the final save, then the
+  checkpointer's ``preempt(deadline_s)``, which abandons older queued
+  snapshots and promotes the final save to its durability tier within the
+  ``preempt_deadline_s`` budget — the outcome lands in
+  :attr:`Trainer.preemption_report`;
 * **straggler monitor**: per-step data-wait vs compute-time is recorded
   (paper Fig. 6: when prefetch works, data-wait ≈ 0), and compute splits
   into the step's dispatch and the sync that brings its metrics to the
@@ -60,7 +60,7 @@ class Trainer:
         state: Dict[str, Any],
         data_iter: Iterable,
         *,
-        checkpointer=None,                     # Direct/BurstBuffer checkpointer
+        checkpointer=None,                     # CheckpointManager-like
         ckpt_every: int = 0,
         resume: bool = True,
         preempt_deadline_s: Optional[float] = None,
@@ -81,26 +81,19 @@ class Trainer:
         self.history: List[Dict] = []
         self._stop_requested = False
         self._preempt_deadline_s = preempt_deadline_s
-        self._pending_saves: List[Any] = []  # AsyncSaveHandle-like objects
+        self._pending_saves: List[Any] = []  # SaveHandles not yet settled
         self.recovered_step: Optional[int] = None
         self.preemption_report = None        # PreemptionReport after a stop
         self.preempt_s: Optional[float] = None  # stop-path wall time
         if install_sigterm:
             signal.signal(signal.SIGTERM, self._handle_sigterm)
         if resume and checkpointer is not None:
-            if hasattr(checkpointer, "resume"):
-                # CheckpointManager path: params AND input-pipeline position
-                # (walks back past corrupt checkpoints; repositions a
-                # ResumableIterator so no sample is skipped or replayed)
-                res = checkpointer.resume(self.state, data_iter=self.data_iter)
-                self.state = res.state
-                self.recovered_step = res.step
-            else:
-                latest = checkpointer.latest_step()
-                if latest is not None:
-                    self.state = checkpointer.restore_pytree(self.state)
-                    self.recovered_step = latest
-                    # step counter lives in the state itself
+            # params AND input-pipeline position (walks back past corrupt
+            # checkpoints; repositions a ResumableIterator so no sample is
+            # skipped or replayed); the step counter lives in the state
+            res = checkpointer.resume(self.state, data_iter=self.data_iter)
+            self.state = res.state
+            self.recovered_step = res.step
 
     def _handle_sigterm(self, signum, frame):  # pragma: no cover
         self._stop_requested = True
@@ -171,10 +164,14 @@ class Trainer:
                 if self.checkpointer is not None:
                     t_pre = time.monotonic()
                     with trace.span(trace.STAGE_PREEMPT, "preempt"):
-                        handle = self._save_checkpoint(step)
+                        self._save_checkpoint(step)
+                        # graceful-shutdown budget: promote the newest
+                        # in-flight save (this one) within the deadline,
+                        # abandon older queued snapshots
                         with trace.span(trace.STAGE_PREEMPT_PROMOTE,
                                         "preempt_promote"):
-                            self._promote(handle)
+                            self.preemption_report = self.checkpointer.preempt(
+                                self._preempt_deadline_s)
                     self.preempt_s = time.monotonic() - t_pre
                 break
         # surface any background write failure that settled during the run
@@ -183,21 +180,11 @@ class Trainer:
         return self.history
 
     # -- checkpointing --------------------------------------------------------
-    def _promote(self, handle) -> None:
-        """Make the preemption save durable before the stop."""
-        preempt = getattr(self.checkpointer, "preempt", None)
-        if callable(preempt):
-            # graceful-shutdown budget: promote the newest in-flight save
-            # (this one) within the deadline, abandon older queued snapshots
-            self.preemption_report = preempt(self._preempt_deadline_s)
-        elif handle is not None:
-            handle.result()
+    def _save_checkpoint(self, step: int) -> None:
+        """Save, and keep the handle until it settles.
 
-    def _save_checkpoint(self, step: int):
-        """Save; returns the async handle if the checkpointer is async.
-
-        Only the blocking portion (full save for a synchronous
-        checkpointer, host snapshot for an async one) lands in
+        Only the blocking portion (the full save for a synchronous
+        snapshot, the host copy for an async one) lands in
         ``timer.checkpoint_s`` — the trainer's view of training-thread
         blocked time."""
         self._reap_saves()
@@ -211,13 +198,10 @@ class Trainer:
                 # the params being saved even under an async engine
                 with trace.span(trace.STAGE_PIPELINE_STATE, "pipeline_state"):
                     extra = {"pipeline": state_fn()}
-            result = self.checkpointer.save(step, self.state,
+            handle = self.checkpointer.save(step, self.state,
                                             extra_meta=extra)
         self.timer.checkpoint_s.append(time.monotonic() - t3)
-        if hasattr(result, "done") and hasattr(result, "exception"):
-            self._pending_saves.append(result)
-            return result
-        return None
+        self._pending_saves.append(handle)
 
     def _reap_saves(self) -> None:
         """Drop completed async saves; re-raise the first background error
@@ -226,7 +210,7 @@ class Trainer:
         error = None
         for h in self._pending_saves:
             if h.done():
-                if getattr(h, "cancelled", lambda: False)():
+                if h.cancelled():
                     continue  # abandoned by preempt(): no error to report
                 e = h.exception()
                 if e is not None and error is None:
@@ -240,7 +224,7 @@ class Trainer:
     def wait_for_checkpoints(self) -> None:
         """Drain all outstanding checkpoint work (async writes, burst-buffer
         drains); surfaces any background error."""
-        if self.checkpointer is not None and hasattr(self.checkpointer, "wait"):
+        if self.checkpointer is not None:
             self.checkpointer.wait()
         self._pending_saves = []
 
@@ -265,11 +249,8 @@ class Trainer:
             data_wait_frac=data_frac,
             straggler_suspect=data_frac > self.straggler_threshold,
             timer=s,
-            blocked_ckpt_s=(
-                list(self.checkpointer.blocked_s)
-                if self.checkpointer is not None and
-                hasattr(self.checkpointer, "blocked_s") else []
-            ),
+            blocked_ckpt_s=(list(self.checkpointer.blocked_s)
+                            if self.checkpointer is not None else []),
             pending_async_saves=sum(
                 1 for h in self._pending_saves if not h.done()
             ),

@@ -1,4 +1,5 @@
-"""AsyncBurstBufferCheckpointer: snapshot-only blocking, both tiers land.
+"""The async two-tier engine (``asyncbb``): snapshot-only blocking, both
+tiers land.
 
 Acceptance criteria covered here:
 
@@ -19,11 +20,14 @@ import time
 import numpy as np
 import pytest
 
-from repro.core.async_burst_buffer import AsyncBurstBufferCheckpointer
-from repro.core.async_checkpoint import AsyncSaveHandle
-from repro.core.burst_buffer import BurstBufferCheckpointer
 from repro.core.checkpoint import CheckpointSaver
+from repro.core.engine import CheckpointEngine, SaveHandle
 from repro.core.faults import FaultInjected, FaultyStorage
+from repro.core.recovery import CheckpointManager
+
+
+def asyncbb(fast, slow, prefix, **kw):
+    return CheckpointEngine((fast, slow), prefix, snapshot="async", **kw)
 
 
 def big_tree(mb=2, seed=0):
@@ -34,12 +38,12 @@ def big_tree(mb=2, seed=0):
 class TestAsyncBurstBuffer:
     def test_roundtrip_both_tiers(self, fast_slow_storage):
         fast, slow = fast_slow_storage
-        abb = AsyncBurstBufferCheckpointer(fast, slow, "ckpt/m", n_shards=2,
-                                           drain_streams=4,
-                                           drain_chunk=256 << 10)
+        abb = CheckpointManager(slow, "ckpt/m", engine="asyncbb",
+                                fast_storage=fast, n_shards=2,
+                                drain_streams=4, drain_chunk=256 << 10)
         t = big_tree(1)
         h = abb.save(7, t)
-        assert isinstance(h, AsyncSaveHandle) and h.step == 7
+        assert isinstance(h, SaveHandle) and h.step == 7
         r = h.result()   # fast tier committed
         assert r.step == 7 and r.n_bytes > 0
         assert abb.fast_saver.latest_step() == 7  # restorable already
@@ -57,13 +61,13 @@ class TestAsyncBurstBuffer:
         the snapshot only."""
         fast, slow = fast_slow_storage
         t = big_tree(8)
-        bb = BurstBufferCheckpointer(fast, slow, "bb/m")
+        bb = CheckpointEngine((fast, slow), "bb/m")
         bb.save(1, t)
         bb_blocked = bb.blocked_s[0]
         bb.wait()
         bb.close()
 
-        abb = AsyncBurstBufferCheckpointer(fast, slow, "abb/m")
+        abb = asyncbb(fast, slow, "abb/m")
         h = abb.save(1, t)
         abb_blocked = abb.blocked_s[0]
         h.result()
@@ -75,8 +79,7 @@ class TestAsyncBurstBuffer:
 
     def test_saves_commit_in_order_on_both_tiers(self, fast_slow_storage):
         fast, slow = fast_slow_storage
-        abb = AsyncBurstBufferCheckpointer(fast, slow, "ckpt/m",
-                                           max_pending=2)
+        abb = asyncbb(fast, slow, "ckpt/m", max_pending=2)
         t = big_tree(1)
         for s in (10, 20, 30):
             abb.save(s, t)
@@ -90,13 +93,15 @@ class TestAsyncBurstBuffer:
         """Satellite regression: ``_pending``/``_drained`` must not grow
         with the number of saves, and old staged steps are evicted."""
         fast, slow = fast_slow_storage
-        abb = AsyncBurstBufferCheckpointer(fast, slow, "ckpt/m", keep=8)
+        abb = CheckpointManager(slow, "ckpt/m", engine="asyncbb",
+                                fast_storage=fast, keep_last=8)
         t = big_tree(1)
         for s in range(1, 7):
             abb.save(s, t)
         abb.wait()
-        with abb._pending_lock:
-            assert abb._pending == [] and abb._drained == set()
+        engine = abb.engine
+        with engine._pending_lock:
+            assert engine._pending == [] and engine._drained == set()
         files = fast.listdir("ckpt")
         assert not any(f.startswith("m-1.data") for f in files)
         assert any(f.startswith("m-6.data") for f in files)
@@ -104,8 +109,7 @@ class TestAsyncBurstBuffer:
 
     def test_backpressure_bounds_inflight_snapshots(self, fast_slow_storage):
         fast, slow = fast_slow_storage
-        abb = AsyncBurstBufferCheckpointer(fast, slow, "ckpt/m",
-                                           max_pending=1)
+        abb = asyncbb(fast, slow, "ckpt/m", max_pending=1)
         t = big_tree(4)
         abb.save(1, t)          # occupies the single slot while staging
         t0 = time.monotonic()
@@ -124,7 +128,7 @@ class TestAsyncBurstBuffer:
             from repro.core.storage import NativeStorage
 
             slow = NativeStorage(d2)
-            abb = AsyncBurstBufferCheckpointer(faulty_fast, slow, "ckpt/m")
+            abb = asyncbb(faulty_fast, slow, "ckpt/m")
             t = big_tree(1)
             abb.save(1, t)
             abb.wait()
@@ -146,8 +150,7 @@ class TestAsyncBurstBuffer:
             from repro.core.storage import NativeStorage
 
             faulty_slow = FaultyStorage(NativeStorage(d2))
-            abb = AsyncBurstBufferCheckpointer(tmp_storage, faulty_slow,
-                                               "ckpt/m")
+            abb = asyncbb(tmp_storage, faulty_slow, "ckpt/m")
             t = big_tree(1)
             abb.save(1, t)
             abb.wait()
@@ -158,13 +161,13 @@ class TestAsyncBurstBuffer:
                 abb.wait()                   # the drain died
             faulty_slow.heal()
             # fast tier kept the step even though the slow tier lost it
-            assert abb.fast_saver.latest_step() == 2
+            assert CheckpointSaver(tmp_storage, "ckpt/m").latest_step() == 2
             assert CheckpointSaver(faulty_slow, "ckpt/m").latest_step() == 1
             abb.close()
 
     def test_save_after_close_raises(self, fast_slow_storage):
         fast, slow = fast_slow_storage
-        abb = AsyncBurstBufferCheckpointer(fast, slow, "ckpt/m")
+        abb = asyncbb(fast, slow, "ckpt/m")
         abb.close()
         with pytest.raises(RuntimeError):
             abb.save(1, big_tree(1))
@@ -185,7 +188,7 @@ class TestTrainerIntegration:
 
     def test_step_loop_never_blocks_past_snapshot(self, fast_slow_storage):
         fast, slow = fast_slow_storage
-        abb = AsyncBurstBufferCheckpointer(fast, slow, "ckpt/m")
+        abb = asyncbb(fast, slow, "ckpt/m")
         tr = self._trainer(abb)
         tr.run(5)
         assert len(abb.blocked_s) == 2      # saves at steps 2 and 4
@@ -198,13 +201,13 @@ class TestTrainerIntegration:
 
     def test_preemption_save_fast_tier_durable(self, fast_slow_storage):
         fast, slow = fast_slow_storage
-        abb = AsyncBurstBufferCheckpointer(fast, slow, "ckpt/m")
+        abb = asyncbb(fast, slow, "ckpt/m")
         tr = self._trainer(abb)
         tr.run(2)
         tr.request_stop()
         tr.run(3)   # stops at the boundary, blocking on the final save
         # handle.result() settles on fast-tier commit: restorable now
-        assert abb.fast_saver.latest_step() == tr.step
+        assert CheckpointSaver(fast, "ckpt/m").latest_step() == tr.step
         tr.wait_for_checkpoints()
         assert CheckpointSaver(slow, "ckpt/m").latest_step() == tr.step
         abb.close()

@@ -2,9 +2,9 @@
 
 Acceptance criteria covered here:
 
-* ``AsyncCheckpointer.save()`` blocking time ≈ snapshot time only — on
-  simulated hdd the training-thread blocked seconds are ≤ 20% of
-  ``DirectCheckpointer``'s;
+* an ``"async"`` snapshot's ``save()`` blocking time ≈ snapshot time
+  only — on simulated hdd the training-thread blocked seconds are ≤ 20%
+  of a ``"sync"`` one's;
 * parallel shard write/restore with ``n_shards=4`` beats serial on a
   simulated tier (the token-bucket model: per-stream bandwidth < aggregate);
 * checkpoint-write spans overlap compute spans in the trace.
@@ -17,9 +17,9 @@ import numpy as np
 import pytest
 
 from repro import trace
-from repro.core.async_checkpoint import AsyncCheckpointer, AsyncSaveHandle
-from repro.core.burst_buffer import DirectCheckpointer
 from repro.core.checkpoint import CheckpointSaver
+from repro.core.engine import CheckpointEngine, SaveHandle
+from repro.core.recovery import CheckpointManager
 from repro.core.storage import SimulatedStorage, TIERS
 
 SCRATCH = "/dev/shm" if os.path.isdir("/dev/shm") else None
@@ -31,6 +31,14 @@ def state(mb=4, seed=0):
         "w": rng.normal(size=(mb * 1024 * 256,)).astype(np.float32),
         "step": np.int32(seed),
     }
+
+
+def async_engine(storage, prefix, **kw):
+    return CheckpointEngine((storage,), prefix, snapshot="async", **kw)
+
+
+def async_manager(storage, prefix, **kw):
+    return CheckpointManager(storage, prefix, engine="async", **kw)
 
 
 def layered_state(n_layers=4, mb_each=2, seed=0):
@@ -56,9 +64,9 @@ def hdd_pair():
 class TestAsyncBasics:
     def test_roundtrip_and_handle(self, tmp_storage):
         t = state(1)
-        ac = AsyncCheckpointer(tmp_storage, "ckpt/m", n_shards=3)
+        ac = async_manager(tmp_storage, "ckpt/m", n_shards=3)
         h = ac.save(7, t)
-        assert isinstance(h, AsyncSaveHandle) and h.step == 7
+        assert isinstance(h, SaveHandle) and h.step == 7
         r = h.result()
         assert r.step == 7 and r.n_bytes > 0
         assert h.done() and h.exception() is None
@@ -70,7 +78,7 @@ class TestAsyncBasics:
 
     def test_saves_commit_in_order(self, tmp_storage):
         t = state(1)
-        ac = AsyncCheckpointer(tmp_storage, "ckpt/m", keep=10)
+        ac = async_manager(tmp_storage, "ckpt/m", keep_last=10)
         handles = [ac.save(s, t) for s in (1, 2, 3, 4)]
         ac.wait()
         assert ac.latest_step() == 4
@@ -83,7 +91,7 @@ class TestAsyncBasics:
         later in-place mutations (numpy leaves are copied)."""
         t = state(1)
         before = t["w"].copy()
-        ac = AsyncCheckpointer(tmp_storage, "ckpt/m")
+        ac = async_manager(tmp_storage, "ckpt/m")
         h = ac.save(1, t)
         t["w"] += 1.0  # training "continues" and mutates in place
         h.result()
@@ -92,7 +100,7 @@ class TestAsyncBasics:
         ac.close()
 
     def test_closed_checkpointer_rejects_saves(self, tmp_storage):
-        ac = AsyncCheckpointer(tmp_storage, "ckpt/m")
+        ac = async_engine(tmp_storage, "ckpt/m")
         ac.close()
         with pytest.raises(RuntimeError):
             ac.save(1, state(1))
@@ -103,10 +111,10 @@ class TestBlockedTime:
         """The acceptance criterion: blocked ≈ snapshot, not the hdd write."""
         direct_st, async_st = hdd_pair
         t = state(4)
-        direct = DirectCheckpointer(direct_st, "d/m")
+        direct = CheckpointEngine((direct_st,), "d/m")
         direct.save(1, t)
 
-        ac = AsyncCheckpointer(async_st, "a/m")
+        ac = async_engine(async_st, "a/m")
         ac.save(1, t)
         ac.wait()
         ac.close()
@@ -119,7 +127,7 @@ class TestBlockedTime:
         t = state(4)
         tracer = trace.start()
         try:
-            ac = AsyncCheckpointer(async_st, "a/m")
+            ac = async_engine(async_st, "a/m")
             ac.save(1, t)
             # training continues while the writer drains to "hdd"
             deadline = time.monotonic() + 2.0
@@ -199,7 +207,7 @@ class TestTrainerIntegration:
 
     def test_step_loop_never_blocks_past_snapshot(self, hdd_pair):
         _, async_st = hdd_pair
-        ac = AsyncCheckpointer(async_st, "ckpt/m")
+        ac = async_manager(async_st, "ckpt/m")
         tr = self._trainer(ac)
         tr.run(5)
         # saves happened (steps 2 and 4) but the loop only paid snapshot time
@@ -212,7 +220,7 @@ class TestTrainerIntegration:
         ac.close()
 
     def test_preemption_save_is_durable(self, tmp_storage):
-        ac = AsyncCheckpointer(tmp_storage, "ckpt/m")
+        ac = async_manager(tmp_storage, "ckpt/m")
         tr = self._trainer(ac)
         tr.run(2)
         tr.request_stop()
@@ -224,7 +232,7 @@ class TestTrainerIntegration:
         from repro.core.faults import FaultInjected, FaultyStorage
 
         faulty = FaultyStorage(tmp_storage)
-        ac = AsyncCheckpointer(faulty, "ckpt/m")
+        ac = async_manager(faulty, "ckpt/m")
         tr = self._trainer(ac)
         faulty.fail_after(0)
         with pytest.raises(FaultInjected):

@@ -3,8 +3,9 @@ import time
 
 import numpy as np
 
-from repro.core.burst_buffer import BurstBufferCheckpointer, DirectCheckpointer
 from repro.core.checkpoint import CheckpointSaver
+from repro.core.engine import CheckpointEngine
+from repro.core.recovery import CheckpointManager
 
 
 def big_tree(mb=2):
@@ -15,7 +16,8 @@ def big_tree(mb=2):
 class TestBurstBuffer:
     def test_drain_completeness(self, fast_slow_storage):
         fast, slow = fast_slow_storage
-        bb = BurstBufferCheckpointer(fast, slow, "ckpt/m", keep=5)
+        bb = CheckpointManager(slow, "ckpt/m", engine="bb",
+                               fast_storage=fast, keep_last=5)
         t = big_tree(1)
         for s in (10, 20, 30):
             bb.save(s, t)
@@ -30,11 +32,11 @@ class TestBurstBuffer:
         """The blocked time must track the fast tier, not the slow one."""
         fast, slow = fast_slow_storage
         t = big_tree(8)
-        direct_slow = DirectCheckpointer(slow, "d/m")
+        direct_slow = CheckpointEngine((slow,), "d/m")
         direct_slow.save(1, t)
         slow_block = direct_slow.blocked_s[0]
 
-        bb = BurstBufferCheckpointer(fast, slow, "bb/m")
+        bb = CheckpointEngine((fast, slow), "bb/m")
         bb.save(1, t)
         bb_block = bb.blocked_s[0]
         bb.wait()
@@ -45,7 +47,7 @@ class TestBurstBuffer:
 
     def test_restore_prefers_fast_tier(self, fast_slow_storage):
         fast, slow = fast_slow_storage
-        bb = BurstBufferCheckpointer(fast, slow, "ckpt/m")
+        bb = CheckpointManager(slow, "ckpt/m", engine="bb", fast_storage=fast)
         t = big_tree(1)
         bb.save(7, t)
         bb.wait()
@@ -56,14 +58,15 @@ class TestBurstBuffer:
 
     def test_restore_falls_back_to_slow(self, fast_slow_storage):
         fast, slow = fast_slow_storage
-        bb = BurstBufferCheckpointer(fast, slow, "ckpt/m")
+        bb = CheckpointManager(slow, "ckpt/m", engine="bb", fast_storage=fast)
         t = big_tree(1)
         bb.save(7, t)
         bb.wait()
         bb.close()
         # simulate losing the burst buffer (node-local NVM gone)
         fast.remove("ckpt")
-        bb2 = BurstBufferCheckpointer(fast, slow, "ckpt/m")
+        bb2 = CheckpointManager(slow, "ckpt/m", engine="bb",
+                                fast_storage=fast)
         out = bb2.restore_pytree(t)
         np.testing.assert_array_equal(out["w"], t["w"])
         bb2.close()
@@ -71,7 +74,8 @@ class TestBurstBuffer:
     def test_fast_tier_cleanup(self, fast_slow_storage):
         """Old staged checkpoints are evicted from the small fast tier."""
         fast, slow = fast_slow_storage
-        bb = BurstBufferCheckpointer(fast, slow, "ckpt/m", keep=5)
+        bb = CheckpointManager(slow, "ckpt/m", engine="bb",
+                               fast_storage=fast, keep_last=5)
         t = big_tree(1)
         for s in (1, 2, 3):
             bb.save(s, t)
@@ -86,7 +90,7 @@ class TestBurstBuffer:
 
 class TestDirect:
     def test_direct_interface(self, tmp_storage):
-        d = DirectCheckpointer(tmp_storage, "ckpt/m", keep=2)
+        d = CheckpointManager(tmp_storage, "ckpt/m", keep_last=2)
         t = big_tree(1)
         d.save(1, t)
         d.save(2, t)
@@ -105,7 +109,7 @@ class TestDirect:
         from repro.core.faults import FaultInjected, FaultyStorage
 
         faulty = FaultyStorage(tmp_storage)
-        d = DirectCheckpointer(faulty, "ckpt/m")
+        d = CheckpointManager(faulty, "ckpt/m")
         t = big_tree(1)
         d.save(1, t)
         faulty.fail_after(0)

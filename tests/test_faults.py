@@ -4,10 +4,10 @@ Every checkpointer documents "a crash mid-save leaves the previous
 checkpoint restorable" — these tests kill the storage at exact points
 (before the commit marker, on the marker itself, during the drain) with
 :class:`FaultyStorage` and assert the previous step survives on every path:
-CheckpointSaver, AsyncCheckpointer, BurstBufferCheckpointer (both tiers),
-and AsyncBurstBufferCheckpointer — the latter under *every* write-op
-injection point of its save/drain path, and under the torn-write and
-reordered-fsync crash models, not just clean op-boundary kills.
+CheckpointSaver and the checkpoint engine's ``async``, ``bb`` (both tiers)
+and ``asyncbb`` presets — the last under *every* write-op injection point
+of its save/drain path, and under the torn-write and reordered-fsync crash
+models, not just clean op-boundary kills.
 """
 import tempfile
 import threading
@@ -16,11 +16,10 @@ import time
 import numpy as np
 import pytest
 
-from repro.core.async_burst_buffer import AsyncBurstBufferCheckpointer
-from repro.core.async_checkpoint import AsyncCheckpointer
-from repro.core.burst_buffer import BurstBufferCheckpointer
 from repro.core.checkpoint import CheckpointSaver
+from repro.core.engine import CheckpointEngine
 from repro.core.faults import FaultInjected, FaultyStorage
+from repro.core.recovery import CheckpointManager
 from repro.core.storage import NativeStorage
 
 
@@ -116,7 +115,7 @@ class TestSaverCrashConsistency:
 class TestAsyncCrashConsistency:
     def test_wait_surfaces_background_write_error(self, tmp_storage):
         faulty = FaultyStorage(tmp_storage)
-        ac = AsyncCheckpointer(faulty, "ckpt/m")
+        ac = CheckpointManager(faulty, "ckpt/m", engine="async")
         t1 = tree(1)
         ac.save(1, t1).result()
         faulty.fail_after(0)
@@ -134,7 +133,7 @@ class TestAsyncCrashConsistency:
         """After a failed save is reported by wait(), a healed device and
         successful later saves must make wait() clean again."""
         faulty = FaultyStorage(tmp_storage)
-        ac = AsyncCheckpointer(faulty, "ckpt/m")
+        ac = CheckpointManager(faulty, "ckpt/m", engine="async")
         faulty.fail_after(0)
         ac.save(1, tree(1))
         with pytest.raises(FaultInjected):
@@ -150,7 +149,8 @@ class TestBurstBufferCrashConsistency:
     def test_fast_tier_crash_mid_save_keeps_previous(self, fast_slow_storage):
         fast, slow = fast_slow_storage
         faulty_fast = FaultyStorage(fast)
-        bb = BurstBufferCheckpointer(faulty_fast, slow, "ckpt/m")
+        bb = CheckpointManager(slow, "ckpt/m", engine="bb",
+                               fast_storage=faulty_fast)
         t1 = tree(1)
         bb.save(1, t1)
         bb.wait()
@@ -172,7 +172,7 @@ class TestBurstBufferCrashConsistency:
             self, fast_slow_storage):
         fast, slow = fast_slow_storage
         faulty_slow = FaultyStorage(slow)
-        bb = BurstBufferCheckpointer(fast, faulty_slow, "ckpt/m")
+        bb = CheckpointEngine((fast, faulty_slow), "ckpt/m")
         t1 = tree(1)
         bb.save(1, t1)
         bb.wait()
@@ -187,7 +187,7 @@ class TestBurstBufferCrashConsistency:
         out = slow_saver.restore_pytree(t1)
         np.testing.assert_array_equal(out["w"], t1["w"])
         # fast tier holds the newer staged step — nothing was lost
-        assert bb.fast_saver.latest_step() == 2
+        assert CheckpointSaver(fast, "ckpt/m").latest_step() == 2
         bb.close()
 
     def test_drain_marker_crash_keeps_slow_consistent(self, fast_slow_storage):
@@ -195,7 +195,7 @@ class TestBurstBufferCrashConsistency:
         are on the slow tier but it must still restore the previous step."""
         fast, slow = fast_slow_storage
         faulty_slow = FaultyStorage(slow)
-        bb = BurstBufferCheckpointer(fast, faulty_slow, "ckpt/m")
+        bb = CheckpointEngine((fast, faulty_slow), "ckpt/m")
         t1 = tree(1)
         bb.save(1, t1)
         bb.wait()
@@ -329,8 +329,8 @@ class TestTornWriteCrashConsistency:
         the slow tier — the un-advanced marker must keep it invisible."""
         fast, slow = fast_slow_storage
         faulty_slow = FaultyStorage(slow)
-        bb = BurstBufferCheckpointer(fast, faulty_slow, "ckpt/m",
-                                     drain_streams=2, drain_chunk=4096)
+        bb = CheckpointEngine((fast, faulty_slow), "ckpt/m",
+                              drain_streams=2, drain_chunk=4096)
         t1 = tree(1)
         bb.save(1, t1)
         bb.wait()
@@ -343,7 +343,8 @@ class TestTornWriteCrashConsistency:
         assert slow_saver.latest_step() == 1
         out = slow_saver.restore_pytree(t1)
         np.testing.assert_array_equal(out["w"], t1["w"])
-        assert bb.fast_saver.latest_step() == 2  # fast tier unaffected
+        # fast tier unaffected
+        assert CheckpointSaver(fast, "ckpt/m").latest_step() == 2
         bb.close()
 
 
@@ -359,8 +360,8 @@ class TestReorderedFsyncCrashConsistency:
         must restore bit-identically."""
         with tempfile.TemporaryDirectory() as d2:
             faulty_slow = FaultyStorage(NativeStorage(d2)).reordered_fsync()
-            bb = BurstBufferCheckpointer(tmp_storage, faulty_slow, "ckpt/m",
-                                         drain_streams=2, drain_chunk=4096)
+            bb = CheckpointEngine((tmp_storage, faulty_slow), "ckpt/m",
+                                  drain_streams=2, drain_chunk=4096)
             t1 = tree(1)
             bb.save(1, t1)
             bb.wait()
@@ -375,8 +376,8 @@ class TestReorderedFsyncCrashConsistency:
         """Same property through the fused engine, across multiple saves."""
         with tempfile.TemporaryDirectory() as d2:
             faulty_slow = FaultyStorage(NativeStorage(d2)).reordered_fsync()
-            abb = AsyncBurstBufferCheckpointer(
-                tmp_storage, faulty_slow, "ckpt/m",
+            abb = CheckpointEngine(
+                (tmp_storage, faulty_slow), "ckpt/m", snapshot="async",
                 drain_streams=2, drain_chunk=4096)
             trees = {s: tree(s) for s in (1, 2, 3)}
             for s in (1, 2, 3):
@@ -401,8 +402,8 @@ class TestAsyncBBInjectionSweep:
     def _make(self, fast_dir, slow_dir):
         fast = FaultyStorage(NativeStorage(fast_dir))
         slow = FaultyStorage(NativeStorage(slow_dir))
-        abb = AsyncBurstBufferCheckpointer(
-            fast, slow, self.PREFIX, n_shards=2,
+        abb = CheckpointEngine(
+            (fast, slow), self.PREFIX, snapshot="async", n_shards=2,
             drain_streams=2, drain_chunk=4096)
         return fast, slow, abb
 

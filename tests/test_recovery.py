@@ -17,7 +17,6 @@ import tempfile
 import numpy as np
 import pytest
 
-from repro.core.burst_buffer import BurstBufferCheckpointer
 from repro.core.checkpoint import CheckpointSaver
 from repro.core.dataset import Dataset, ResumableIterator
 from repro.core.faults import FaultInjected, FaultyStorage, TransientFault
@@ -207,7 +206,7 @@ class TestMidDrainKill:
     alone (the node — and its fast tier — is gone)."""
 
     def _run_with_bb(self, fast, slow, consumed):
-        bb = BurstBufferCheckpointer(fast, slow, PREFIX)
+        bb = CheckpointManager(slow, PREFIX, engine="bb", fast_storage=fast)
         tr = make_trainer(bb, consumed)
         tr.run(N_STEPS)
         return tr, bb

@@ -12,7 +12,7 @@ import pytest
 
 from repro.configs import ALEXNET_SMOKE as ACFG
 from repro.core import (
-    BurstBufferCheckpointer, Dataset, IOTracer, image_pipeline, make_storage,
+    CheckpointManager, Dataset, IOTracer, image_pipeline, make_storage,
 )
 from repro.core import records
 from repro.core.microbench import run_microbench, thread_scaling_sweep
@@ -67,7 +67,8 @@ class TestEndToEnd:
                 tempfile.TemporaryDirectory() as d2:
             fast = make_storage("optane", d1, time_scale=0.02)
             slow = make_storage("hdd", d2, time_scale=0.02)
-            bb = BurstBufferCheckpointer(fast, slow, "ckpt/alexnet")
+            bb = CheckpointManager(slow, "ckpt/alexnet", engine="bb",
+                                   fast_storage=fast)
             tr = Trainer(train_step, state, iter(ds), checkpointer=bb,
                          ckpt_every=3)
             hist = tr.run(6)
@@ -82,7 +83,8 @@ class TestEndToEnd:
             # restart picks up where we left off
             state2 = {"params": A.init_params(jax.random.PRNGKey(1), ACFG),
                       "step": jnp.int32(0)}
-            bb2 = BurstBufferCheckpointer(fast, slow, "ckpt/alexnet")
+            bb2 = CheckpointManager(slow, "ckpt/alexnet", engine="bb",
+                                    fast_storage=fast)
             tr2 = Trainer(train_step, state2, iter(ds), checkpointer=bb2)
             assert tr2.step == 6
             bb2.close()

@@ -337,12 +337,12 @@ class TestInstrumentation:
     def test_burst_buffer_drain_span(self, fast_slow_storage):
         import numpy as np
 
-        from repro.core.burst_buffer import BurstBufferCheckpointer
+        from repro.core.engine import CheckpointEngine
 
         fast, slow = fast_slow_storage
         tr = trace.start()
         try:
-            bb = BurstBufferCheckpointer(fast, slow, "ckpt/m", sync=False)
+            bb = CheckpointEngine((fast, slow), "ckpt/m", sync=False)
             bb.save(1, {"w": np.ones(256, np.float32)})
             bb.wait()
             bb.close()

@@ -5,7 +5,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core.burst_buffer import DirectCheckpointer
+from repro.core.recovery import CheckpointManager
 from repro.train.trainer import Trainer
 
 
@@ -43,21 +43,21 @@ class TestTrainerLoop:
 
     def test_checkpoint_every_k(self, tmp_storage):
         state, step_fn, data = toy_setup()
-        ck = DirectCheckpointer(tmp_storage, "ckpt/m", keep=10)
+        ck = CheckpointManager(tmp_storage, "ckpt/m", keep_last=10)
         tr = Trainer(step_fn, state, data, checkpointer=ck, ckpt_every=2)
         tr.run(6)
         assert ck.saver.all_steps() == [2, 4, 6]
 
     def test_restart_resumes_from_checkpoint(self, tmp_storage):
         state, step_fn, data = toy_setup()
-        ck = DirectCheckpointer(tmp_storage, "ckpt/m")
+        ck = CheckpointManager(tmp_storage, "ckpt/m")
         tr = Trainer(step_fn, state, data, checkpointer=ck, ckpt_every=3)
         tr.run(3)
         w_after_3 = np.asarray(jax.device_get(tr.state["params"]["w"]))
 
         # "crash" and restart from a fresh initial state
         state2, step_fn2, data2 = toy_setup()
-        ck2 = DirectCheckpointer(tmp_storage, "ckpt/m")
+        ck2 = CheckpointManager(tmp_storage, "ckpt/m")
         tr2 = Trainer(step_fn2, state2, data2, checkpointer=ck2, resume=True)
         assert tr2.step == 3
         np.testing.assert_allclose(
@@ -65,7 +65,7 @@ class TestTrainerLoop:
 
     def test_preemption_checkpoints_and_stops(self, tmp_storage):
         state, step_fn, data = toy_setup()
-        ck = DirectCheckpointer(tmp_storage, "ckpt/m")
+        ck = CheckpointManager(tmp_storage, "ckpt/m")
         tr = Trainer(step_fn, state, data, checkpointer=ck)
         tr.request_stop()
         tr.run(100)
